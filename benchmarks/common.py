@@ -15,9 +15,13 @@ from repro.configs.base import ModelConfig
 from repro.core.federated import FederatedConfig, FederatedRunner
 from repro.core.spec import ClientCohort, FederationSpec
 from repro.data.synthetic import synthetic_multimodal_corpus
+from repro.launch.mesh import use_compile_cache
 from repro.models.model import build_model
 
 RESULTS_DIR = os.path.join("experiments", "results")
+
+# every benchmark process imports this module: one compile cache for all
+use_compile_cache()
 
 _COMMON = dict(n_modalities=3, modality_dim=32, n_soft_tokens=4,
                connector_dim=48, remat=False, activation="gelu",

@@ -26,7 +26,9 @@ from repro.configs.base import ModelConfig  # noqa: E402
 from repro.core.federated import FederatedRunner  # noqa: E402
 from repro.core.spec import ClientCohort, FederationSpec  # noqa: E402
 from repro.data.synthetic import synthetic_multimodal_corpus  # noqa: E402
-from repro.launch.mesh import make_federated_mesh  # noqa: E402
+from repro.launch.mesh import make_federated_mesh, use_compile_cache  # noqa: E402
+
+use_compile_cache()
 
 KW = dict(n_modalities=3, modality_dim=32, n_soft_tokens=4, connector_dim=48,
           lora_rank=4, remat=False, activation="gelu", vocab_size=128)

@@ -14,8 +14,11 @@ from repro.core import ccl as ccl_lib
 from repro.core import lora, mma
 from repro.data.pipeline import batches
 from repro.data.synthetic import synthetic_multimodal_corpus
+from repro.launch.mesh import use_compile_cache
 from repro.models.model import build_model
 from repro.optim.adamw import adamw
+
+use_compile_cache()
 
 cfg = ModelConfig(name="quickstart", family="dense", n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,

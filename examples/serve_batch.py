@@ -12,11 +12,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.core import ccl as ccl_lib
+from repro.launch.mesh import use_compile_cache
 from repro.launch.serve import generate
 from repro.models.model import build_model
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b",
                     help="assigned arch id (reduced variant is served)")

@@ -14,11 +14,13 @@ import numpy as np
 from repro.configs.base import get_config
 from repro.core import ccl as ccl_lib
 from repro.core.spec import ClientCohort, FederationSpec
+from repro.launch.mesh import use_compile_cache
 from repro.launch.serve_engine import CohortServer, EngineConfig, ServingEngine
 from repro.models.model import build_model
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b",
                     help="assigned arch id (reduced variant is served)")

@@ -13,6 +13,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import batches
 from repro.data.synthetic import synthetic_multimodal_corpus
+from repro.launch.mesh import use_compile_cache
 from repro.launch.train import run_training
 from repro.models.model import build_model
 
@@ -35,6 +36,7 @@ def cfg_small():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--small", action="store_true")

@@ -431,6 +431,7 @@ class FederatedRunner:
             if spec.sampler is not None else None)
         self._store = (ClientStore(directory=store_dir)
                        if self._schedule is not None else None)
+        self._cohort_bases = None    # frozen base per cohort (sampler only)
         self._rnd_locals = None      # per-cohort sampled LOCAL indices
         self._rnd_ids = None         # (S,) sampled GLOBAL client ids
         self._rnd_no = None          # the round index the draws belong to
@@ -457,17 +458,16 @@ class FederatedRunner:
         # is therefore exactly the personal subset: a registered
         # population costs one backbone per cohort plus N personal sets,
         # not N full models.
-        self._cohort_bases = [
-            ccl_lib.init_unified(keys[spec.offsets[c]], bundles[c])
-            for c in range(spec.n_cohorts)]
+        bases = [ccl_lib.init_unified(keys[spec.offsets[c]], bundles[c])
+                 for c in range(spec.n_cohorts)]
         device_params = []
         for j in range(N):
             c = spec.cohort_of(j)
             if j == spec.offsets[c]:
-                device_params.append(self._cohort_bases[c])
+                device_params.append(bases[c])
             else:
                 device_params.append(lora.combine(
-                    self._cohort_bases[c],
+                    bases[c],
                     lora.partition(ccl_lib.init_unified(keys[j],
                                                         bundles[c]))))
         self.server_llm = ccl_lib.init_unified(keys[-1], self.llm)
@@ -481,8 +481,11 @@ class FederatedRunner:
         # registered population: push every client's personal state into
         # the host/disk-resident store; the engines then gather each
         # round's sampled working set into the stacked buffers and scatter
-        # the updates back (device memory scales with the working set)
+        # the updates back (device memory scales with the working set).
+        # Only then is the frozen base kept apart from the client state:
+        # store entries hold personal leaves alone.
         if self._store is not None:
+            self._cohort_bases = bases
             for j in range(N):
                 entry = {"train": lora.partition(device_params[j]),
                          "opt": device_opt[j]}
@@ -524,7 +527,10 @@ class FederatedRunner:
             rt.shared = lora.shared_keys(up0, server_lora)
             rt.own = tuple(k for k in sorted(up0) if k not in rt.shared)
             rt.own_dtypes = {k: up0[k].dtype for k in rt.own}
-            rt.last_global = {k: server_lora[k] for k in rt.shared}
+            # a copy, not the server SLM's own leaves: the round donates
+            # the server trees, and a donated buffer may not also be read
+            # through another argument of the same call
+            rt.last_global = {k: jnp.copy(server_lora[k]) for k in rt.shared}
             self._cohorts.append(rt)
         if self._schedule is not None:
             woff = 0
@@ -1095,8 +1101,12 @@ class FederatedRunner:
                 # step count and every shape stay those of the clean trace)
                 p = _where_clients(present[0], p, states[0][0])
                 o = _where_clients(present[0], o, states[0][1])
-            # the model devices actually serve between rounds (client eval)
-            post_amt = (p,)
+            # the model devices actually serve between rounds (client eval):
+            # only its LoRA leaves differ from the delivered tree, so only
+            # those leave the jit (the caller splices them back in) — a
+            # second full stacked copy of the backbone would not fit next
+            # to the first at published widths
+            post_amt = (lora.partition(p, lora.is_lora_leaf),)
 
             if cfg.mode == "standalone":
                 return (post_amt, ((p, o),), server_llm, server_slm,
@@ -1159,7 +1169,10 @@ class FederatedRunner:
             return (post_amt, ((p, o),), server_llm, server_slm,
                     server_llm_opt, server_slm_opt, (down,), chan_states)
 
-        return jax.jit(round_fn)
+        # the round consumes the client stacks and the server trees and
+        # returns their successors: donating them lets the outputs reuse
+        # the inputs' device memory (one copy of each model, not two)
+        return jax.jit(round_fn, donate_argnums=(0, 1, 2, 3, 4))
 
     # ------------------------------------------------------------------
     # overlap engine: the round split into per-cohort device phases and a
@@ -1524,7 +1537,8 @@ class FederatedRunner:
             cur = lora.partition(self.server_slm, lora.is_lora_leaf)
             agg = dict(agg)
             for k in missing:
-                agg[k] = cur[k]
+                # a copy: the server phase may donate the server SLM
+                agg[k] = jnp.copy(cur[k])
         return agg
 
     def _apply_deliveries(self, down, own_avgs) -> None:
@@ -1557,15 +1571,16 @@ class FederatedRunner:
         splices the broadcast delivery into each cohort's stacked tree
         eagerly, so the frozen bulk passes through by reference — a jitted
         combine would copy every client's full frozen parameters each
-        round (CPU has no donation), which at N=64 costs more than the
-        server phase saves.
+        round, which at N=64 costs more than the server phase saves.
 
-        Optimizer states are donated (each chain exclusively owns its own);
-        parameter trees are NOT — under ``staleness >= 1`` a stale anchor
-        model or an unapplied ``down`` legitimately outlives the next phase
-        dispatch, and donating it would invalidate a live reference.  CPU
-        backends have no donation support, so donation is skipped there to
-        avoid per-call warnings.
+        The client stacks are donated to the device phase (each cohort's
+        chain exclusively owns them, and the caller replaces them with the
+        outputs), and the server optimizer states to the server phase.
+        The server parameter trees are donated only by the split schedule:
+        in the overlap engine a stale anchor model or an unapplied ``down``
+        legitimately outlives the next phase dispatch, and on one device
+        the anchor base IS the live server LLM, so donating it would
+        invalidate a live reference.
         """
         cfg = self.cfg
         se_step = self._se_step_raw
@@ -1575,11 +1590,9 @@ class FederatedRunner:
         robust = cfg.robust
         with_faults = self._faults is not None
         chan = self.channel
-        on_cpu = jax.default_backend() == "cpu"
-        # under faults the pre-round stacked state feeds the freeze-select,
-        # so the opt buffers cannot be donated to the chain
-        donate_dev = () if (on_cpu or with_faults) else (1,)  # stacked_opt
-        donate_srv = () if on_cpu else (2, 3)        # server opt states
+        donate_dev = (0, 1)                           # client stacks
+        donate_srv = ((0, 1, 2, 3) if self.engine == "vectorized"
+                      else (2, 3))                    # server trees / opts
 
         def make_device_phase(rt: _Cohort):
             ccl_step, amt_step = self._make_device_steps(rt)
@@ -1838,6 +1851,8 @@ class FederatedRunner:
             css, self._chan_rnd())
         for rt, (p, o), lg in zip(self._cohorts, states, lgs):
             rt.stacked_params, rt.stacked_opt, rt.last_global = p, o, lg
+        post_amt = tuple(lora.combine(rt.stacked_params, pa)
+                         for rt, pa in zip(self._cohorts, post_amt))
         if self.channel.stateful:
             for rt, cs in zip(self._cohorts, css):
                 rt.chan_state = cs

@@ -190,15 +190,16 @@ def communicated_fraction(params,
 
 
 def merge_lora(params, cfg):
-    """Fold LoRA updates into the frozen weights (W' = W + (α/r) BA) —
-    used before serving so decode pays no adapter cost."""
+    """Fold LoRA updates into the frozen weights (W' = W + (α/r) BA) and
+    drop the adapter leaves — used before serving so decode pays no
+    adapter cost (and applies each update exactly once: a merged weight
+    next to its adapter would apply BA twice)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     by_path = {path_str(p): (p, leaf) for p, leaf in flat}
     scale = cfg.lora_alpha / cfg.lora_rank
     new = {}
     for s, (p, leaf) in by_path.items():
         if is_lora_leaf(s):
-            new[s] = leaf
             continue
         a_key, b_key = s + "_lora_a", s + "_lora_b"
         if a_key in by_path:
@@ -208,5 +209,15 @@ def merge_lora(params, cfg):
                     + scale * (a.astype(jnp.float32)
                                @ b.astype(jnp.float32))).astype(leaf.dtype)
         new[s] = leaf
-    leaves = [new[path_str(p)] for p, _ in flat]
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    return _drop_lora(jax.tree_util.tree_unflatten(
+        treedef, [new.get(path_str(p)) for p, _ in flat]))
+
+
+def _drop_lora(tree):
+    """``tree`` without its adapter entries (nested dicts/lists)."""
+    if isinstance(tree, dict):
+        return {k: _drop_lora(v) for k, v in tree.items()
+                if not is_lora_leaf(str(k))}
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(_drop_lora(v) for v in tree)
+    return tree

@@ -3,7 +3,9 @@
 ``interpret`` defaults to True on CPU (this container) and False on real TPU
 backends — the kernels are written for TPU (pl.pallas_call + BlockSpec VMEM
 tiling) and *validated* in interpret mode against the pure-jnp oracles in
-``ref.py``.
+``ref.py``.  The serving and wire-codec wrappers (``paged_attention``,
+``quantize``, ``dequantize``) choose kernel or jnp twin by the platform a
+computation is compiled for (``_kernel_or_twin``).
 """
 from __future__ import annotations
 
@@ -21,6 +23,22 @@ from repro.kernels.ssd_scan import ssd_chunk as _ssd_chunk
 
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _kernel_or_twin(kernel, twin, use_kernel, interpret):
+    """``kernel(interpret)`` or ``twin()``.  ``use_kernel`` None decides per
+    compile target: the Pallas kernel where the computation is lowered for
+    a TPU, the jnp twin anywhere else.  The choice is made at lowering
+    (``lax.platform_dependent``), not from the process's default backend,
+    so a program compiled for a TPU holds the kernel even when it is
+    compiled from a host without one."""
+    if use_kernel is None:
+        return jax.lax.platform_dependent(
+            tpu=lambda: kernel(bool(interpret)), default=twin)
+    if use_kernel:
+        return kernel(default_interpret() if interpret is None
+                      else interpret)
+    return twin()
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -50,36 +68,39 @@ def paged_attention(q, k_pages, v_pages, block_tables, lens, window, *,
     window: scalar int32 (layers.BIG_WINDOW = none; may be traced — the
     per-layer window rides through the model's layer scan).
 
-    Returns (B, 1, H * D).  ``use_kernel`` None = kernel on TPU, pure-jnp
-    gather path elsewhere (the Pallas grid walks one page per step, which
-    interpret mode would execute as a Python loop — correct but slow; the
-    jnp path is the serving fast path on CPU and the oracle's twin).
+    Returns (B, 1, H * D).  ``use_kernel`` None = kernel when compiled for
+    TPU, pure-jnp gather path elsewhere (the Pallas grid walks one page per
+    step, which interpret mode would execute as a Python loop — correct
+    but slow; the jnp path is the serving fast path on CPU and the
+    oracle's twin).
     """
     B, _, H, D = q.shape
     ps, K = k_pages.shape[1], k_pages.shape[2]
     M = block_tables.shape[1]
-    if use_kernel is None:
-        use_kernel = not default_interpret()
-    if use_kernel:
-        interpret = default_interpret() if interpret is None else interpret
+
+    def kernel(interp):
         out = _paged(q, k_pages, v_pages, block_tables, lens, window,
-                     interpret=interpret)
+                     interpret=interp)
         return out.reshape(B, 1, H * D)
-    # jnp fast path — mha math inlined (models.layers imports would cycle)
-    G = H // K
-    import math as _math
-    k = k_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
-    qf = q.astype(jnp.float32).reshape(B, K, G, D)
-    logits = jnp.einsum("bkgd,bskd->bkgs", qf, k) / _math.sqrt(D)
-    qpos = lens[:, None] - 1
-    kpos = jnp.arange(M * ps, dtype=jnp.int32)[None, :]
-    mask = (kpos <= qpos) & (qpos - kpos < window)
-    logits = jnp.where(mask[:, None, None], logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    w = jnp.where(lens[:, None, None, None] > 0, w, 0.0)
-    out = jnp.einsum("bkgs,bskd->bkgd", w, v)
-    return out.reshape(B, 1, H * D).astype(q.dtype)
+
+    def twin():
+        # mha math inlined (models.layers imports would cycle)
+        G = H // K
+        import math as _math
+        k = k_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
+        v = v_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
+        qf = q.astype(jnp.float32).reshape(B, K, G, D)
+        logits = jnp.einsum("bkgd,bskd->bkgs", qf, k) / _math.sqrt(D)
+        qpos = lens[:, None] - 1
+        kpos = jnp.arange(M * ps, dtype=jnp.int32)[None, :]
+        mask = (kpos <= qpos) & (qpos - kpos < window)
+        logits = jnp.where(mask[:, None, None], logits, -1e30)
+        w = jax.nn.softmax(logits, axis=-1)
+        w = jnp.where(lens[:, None, None, None] > 0, w, 0.0)
+        out = jnp.einsum("bkgs,bskd->bkgd", w, v)
+        return out.reshape(B, 1, H * D).astype(q.dtype)
+
+    return _kernel_or_twin(kernel, twin, use_kernel, interpret)
 
 
 def gram_log_volume(vs, mask=None, eps: float = 1e-5, interpret=None):
@@ -108,49 +129,49 @@ def quantize(x, qmax: int = 127, *, use_kernel=None, interpret=None):
     """Per-row symmetric abs-max quantization.  x: (R, L) — one wire tile
     per row — returns ``(q int8 (R, L), scale f32 (R,))``.
 
-    ``use_kernel`` None = Pallas kernel on TPU, pure-jnp twin elsewhere
-    (the twin IS the oracle math, so CPU engine parity is exact).  The
-    kernel grid needs R to be a multiple of the 128-row block, so prime
-    row counts are padded with all-zero rows (scale 0, codes 0) and
+    ``use_kernel`` None = Pallas kernel when compiled for TPU, pure-jnp twin
+    elsewhere (the twin IS the oracle math, so CPU engine parity is exact).
+    The kernel grid needs R to be a multiple of the 128-row block, so
+    prime row counts are padded with all-zero rows (scale 0, codes 0) and
     sliced off — same precedent as ``gram_log_volume``.
     """
-    if use_kernel is None:
-        use_kernel = not default_interpret()
-    if use_kernel:
-        interpret = default_interpret() if interpret is None else interpret
+    def kernel(interp):
         R = x.shape[0]
         br = R if R <= 128 else 128
         pad = -R % br
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.zeros((pad, x.shape[1]), x.dtype)])
-        q, s = _quant(x, qmax=qmax, br=br, interpret=interpret)
+        xp = (jnp.concatenate([x, jnp.zeros((pad, x.shape[1]), x.dtype)])
+              if pad else x)
+        q, s = _quant(xp, qmax=qmax, br=br, interpret=interp)
         return (q[:R], s[:R]) if pad else (q, s)
-    xf = x.astype(jnp.float32)
-    # scale := absmax * (1/qmax) — bitwise-pinned to ref.quantize_ref
-    scale = jnp.max(jnp.abs(xf), axis=-1) * jnp.float32(1.0 / qmax)
-    safe = jnp.where(scale > 0.0, scale, 1.0)
-    q = jnp.clip(jnp.round(xf / safe[:, None]), -qmax, qmax)
-    return q.astype(jnp.int8), scale
+
+    def twin():
+        xf = x.astype(jnp.float32)
+        # scale := absmax * (1/qmax) — bitwise-pinned to ref.quantize_ref
+        scale = jnp.max(jnp.abs(xf), axis=-1) * jnp.float32(1.0 / qmax)
+        safe = jnp.where(scale > 0.0, scale, 1.0)
+        q = jnp.clip(jnp.round(xf / safe[:, None]), -qmax, qmax)
+        return q.astype(jnp.int8), scale
+
+    return _kernel_or_twin(kernel, twin, use_kernel, interpret)
 
 
 def dequantize(q, scale, *, use_kernel=None, interpret=None):
     """Inverse of :func:`quantize`: (R, L) int8 + (R,) f32 scales -> f32."""
-    if use_kernel is None:
-        use_kernel = not default_interpret()
-    if use_kernel:
-        interpret = default_interpret() if interpret is None else interpret
+    def kernel(interp):
         R = q.shape[0]
         br = R if R <= 128 else 128
         pad = -R % br
+        qp, sp = q, scale
         if pad:
-            q = jnp.concatenate(
-                [q, jnp.zeros((pad, q.shape[1]), q.dtype)])
-            scale = jnp.concatenate(
-                [scale, jnp.zeros((pad,), scale.dtype)])
-        out = _dequant(q, scale, br=br, interpret=interpret)
+            qp = jnp.concatenate([q, jnp.zeros((pad, q.shape[1]), q.dtype)])
+            sp = jnp.concatenate([scale, jnp.zeros((pad,), scale.dtype)])
+        out = _dequant(qp, sp, br=br, interpret=interp)
         return out[:R] if pad else out
-    return q.astype(jnp.float32) * scale[:, None]
+
+    def twin():
+        return q.astype(jnp.float32) * scale[:, None]
+
+    return _kernel_or_twin(kernel, twin, use_kernel, interpret)
 
 
 def lora_matmul(x, w, a, b, scale: float = 1.0, interpret=None, **blocks):

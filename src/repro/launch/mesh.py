@@ -12,6 +12,10 @@ import jax
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
+# the checkout root: four directories above src/repro/launch/mesh.py
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
 _TCMALLOC_CANDIDATES = (
     "/usr/lib/x86_64-linux-gnu/libtcmalloc.so.4",
     "/usr/lib/x86_64-linux-gnu/libtcmalloc_minimal.so.4",
@@ -76,21 +80,51 @@ def force_host_device_count(n: int) -> None:
             f"XLA_FLAGS={_FORCE_FLAG}={n} in the environment before the "
             "first jax call instead")
 
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives in
+    ``<checkout>/.jax_cache`` (listed in ``.gitignore``) — a fixed path,
+    never a temporary name, a pid or a time, because a later process finds
+    an entry only under the directory that wrote it.  Call it once, before
+    the first compile, from every entry point (scripts, benchmarks, the
+    test ``conftest.py``).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # hardware constants used by the roofline (per chip)
 PEAK_FLOPS_BF16 = 197e12         # FLOP/s
 HBM_BW = 819e9                   # B/s
 ICI_BW = 50e9                    # B/s per link
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the compiler propagates shardings
+    from the placed arguments.  ``jax.make_mesh`` otherwise makes
+    ``Explicit`` axes, which put shardings into the traced types — and the
+    engines' unsharded intermediates (``jnp.ones`` masks concatenated with
+    client-sharded data) then fail to trace on more than one device."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke runs of the same SPMD code."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_federated_mesh(n_model: int = 1):
@@ -100,7 +134,7 @@ def make_federated_mesh(n_model: int = 1):
     host this degenerates to the (1, 1) host mesh, so the engine stays
     exact there."""
     n_data = max(1, len(jax.devices()) // max(1, n_model))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_cohort_meshes(n_cohorts: int, n_model: int = 1):

@@ -178,12 +178,7 @@ def moe_mlp_sharded(p, cfg: ModelConfig, x):
         y = jax.lax.psum(y, "model")
         return y.reshape(B_loc, S, d), aux
 
-    # jax.shard_map only exists from jax 0.6; fall back to the experimental
-    # home it had before that
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(w_specs["router"], w_specs["we_gate"], w_specs["we_up"],
                   w_specs["we_down"], x_spec),

@@ -27,16 +27,16 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
+from repro.launch.mesh import use_compile_cache  # noqa: E402
+
 # Persistent XLA compilation cache: the suite is dominated by jit time on
 # small CPU boxes (a fused federated round is ~40 s of XLA), and the
 # compiled artifacts are identical across runs.  First (cold) run pays
-# full compile and populates .jax_cache/; warm runs load from disk (~4x
-# faster suite).  Results are bit-identical either way.  Honor an explicit
-# JAX_COMPILATION_CACHE_DIR; CI caches this directory across builds.
-if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+# full compile and populates the cache; warm runs load from disk (~4x
+# faster suite).  Results are bit-identical either way.  The directory is
+# JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache; CI caches
+# it across builds.
+use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 from repro.configs.base import ModelConfig  # noqa: E402

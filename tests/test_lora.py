@@ -69,10 +69,9 @@ def test_merge_lora_forward_equivalence(setup):
     logits_adapter, _ = bundle.logits(params, batch)
 
     merged = lora.merge_lora(params, cfg)
-    # zero the adapters in the merged tree: their effect is now in W
-    zeroed = {k: jnp.zeros_like(v)
-              for k, v in lora.partition(merged, lora.is_lora_leaf).items()}
-    merged = lora.combine(merged, zeroed)
+    # the adapters are gone from the merged tree: their effect is now in W,
+    # and keeping them would apply BA a second time
+    assert lora.partition(merged, lora.is_lora_leaf) == {}
     logits_merged, _ = bundle.logits(merged, batch)
     np.testing.assert_allclose(np.asarray(logits_adapter, np.float32),
                                np.asarray(logits_merged, np.float32),

@@ -69,7 +69,8 @@ def test_standalone_mode_never_communicates(corpus):
                          local_steps_amt=1, server_steps=1, batch_size=8,
                          mode="standalone")
     runner = FederatedRunner(fc, slm, llm, corpus)
-    before = jax.tree.leaves(runner.server_slm)
+    # host copies: the round donates the server trees it replaces
+    before = [np.array(x) for x in jax.tree.leaves(runner.server_slm)]
     runner.run_round()
     after = jax.tree.leaves(runner.server_slm)
     for a, b in zip(before, after):
