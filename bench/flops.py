@@ -1,0 +1,115 @@
+"""Operations and bytes the algorithms need, from shapes alone.
+
+FLOPs count multiply-adds as two.  Model FLOPs: recomputation, padding of
+prompts to compile buckets and of the vocabulary are not counted.
+"""
+from __future__ import annotations
+
+import math
+
+
+def _layer_matmul(m: dict) -> int:
+    """Weights a token meets in one layer's matmuls (attention + MLP)."""
+    d, H, K, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                      m["head_dim"], m["d_ff"])
+    mult = 3 if m["activation"] in ("silu", "geglu") else 2
+    return d * (H + 2 * K) * hd + H * hd * d + mult * d * f
+
+
+def _layer_lora(m: dict) -> int:
+    d, H, K, hd, r = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                      m["head_dim"], m["lora_rank"])
+    dims = {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
+            "wo": (H * hd, d)}
+    return sum(r * (dims[t][0] + dims[t][1]) for t in m["lora_targets"])
+
+
+def causal_pairs(S: int) -> int:
+    """(query, key) pairs a causal sequence of length S scores."""
+    return S * (S + 1) // 2
+
+
+def forward_flops(m: dict, n_tok: int, pairs: int, logit_pos: int,
+                  lora: bool = True) -> int:
+    """One forward pass over ``n_tok`` tokens with ``pairs`` attention
+    score pairs and logits at ``logit_pos`` positions."""
+    L, H, hd = m["n_layers"], m["n_heads"], m["head_dim"]
+    w = _layer_matmul(m) + (_layer_lora(m) if lora else 0)
+    return (2 * n_tok * L * w + 4 * pairs * H * hd * L
+            + 2 * logit_pos * m["d_model"] * m["vocab_size"])
+
+
+def train_flops(m: dict, B: int, S: int) -> int:
+    """Forward, backward to the activations, and the LoRA gradients, of a
+    batch of B causal sequences of length S (logits at every position)."""
+    n, pairs = B * S, B * causal_pairs(S)
+    L, H, hd = m["n_layers"], m["n_heads"], m["head_dim"]
+    fwd = forward_flops(m, n, pairs, n)
+    attn = 4 * pairs * H * hd * L
+    bwd = fwd + attn                   # attention backward is 4 matmuls
+    lora_w = 2 * n * L * _layer_lora(m)
+    return fwd + bwd + lora_w
+
+
+def round_flops(conf: dict, job: dict) -> int:
+    """One ML-ECS round: every client's CCL and AMT steps, and the SE-CCL
+    steps (the LLM and the server SLM, each with the soft prompt for its
+    own loss and without it for the logit transfer)."""
+    slm, llm = conf["clients"]["model"], conf["server_llm"]
+    B, S = job["batch_size"], job["seq_len"]
+    P = slm["n_soft_tokens"]
+    steps = job["local_steps_ccl"] + job["local_steps_amt"]
+    clients = conf["clients"]["n"] * steps * train_flops(slm, B, P + S)
+    se = job["server_steps"] * (
+        train_flops(llm, B, llm["n_soft_tokens"] + S) + train_flops(llm, B, S)
+        + train_flops(slm, B, P + S) + train_flops(slm, B, S))
+    return clients + se
+
+
+def prefill_flops(m: dict, n: int) -> int:
+    """B=1 prefill of n positions (soft prompt included), logits at the
+    last; LoRA merged."""
+    return forward_flops(m, n, causal_pairs(n), 1, lora=False)
+
+
+def decode_flops(m: dict, ctx: int) -> int:
+    """One decoded token attending to ``ctx`` cached positions (itself
+    included); LoRA merged."""
+    return forward_flops(m, 1, ctx, 1, lora=False)
+
+
+def live_kv_bytes(m: dict, lens, page_size: int, itemsize: int = 2) -> int:
+    """Bytes one paged decode step must move: the live K/V pages of each
+    active slot (``lens``: cached entries incl. the new token), plus its
+    query and output rows, over every layer."""
+    K, H, hd, L = m["n_kv_heads"], m["n_heads"], m["head_dim"], m["n_layers"]
+    pages = sum(math.ceil(x / page_size) for x in lens)
+    kv = pages * page_size * K * hd * 2 * itemsize
+    qo = len(lens) * H * hd * 2 * itemsize
+    return (kv + qo) * L
+
+
+def codec_bytes(conf: dict, job: dict) -> int:
+    """Bytes the quantize / dequantize kernels need in one round: every
+    client's upload quantized once and dequantized twice (error feedback
+    and the server's decode), the delivery quantized and dequantized once.
+    A tile row is ``block`` values: f32 in, int8 codes and one f32 scale
+    out, and back."""
+    chan = job.get("channel")
+    if not chan:
+        return 0
+    m = conf["clients"]["model"]
+    block = chan.get("block", 128)
+    L, r = m["n_layers"], m["lora_rank"]
+    d, H, K, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    dims = {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
+            "wo": (H * hd, d)}
+    rows = 0
+    for t in m["lora_targets"]:
+        i, o = dims[t]
+        rows += math.ceil(L * i * r / block) + math.ceil(L * r * o / block)
+    q = rows * (block * 4 + block + 4)      # f32 in, int8 + scale out
+    dq = rows * (block + 4 + block * 4)     # int8 + scale in, f32 out
+    n = conf["clients"]["n"]
+    up_dq = 2 if chan.get("error_feedback", True) else 1
+    return n * (q + up_dq * dq) + q + dq
