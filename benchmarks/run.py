@@ -1,5 +1,5 @@
-"""Benchmark harness — one module per paper table/figure + roofline +
-kernel microbench.  Prints ``name,metric,derived`` CSV rows.
+"""Benchmark harness — one module per paper table/figure + kernel
+microbench.  Prints ``name,metric,derived`` CSV rows.
 
 Each benchmark runs in its OWN subprocess: the XLA CPU JIT accumulates
 compiled dylibs per process and a full federated sweep exhausts its budget
@@ -18,7 +18,7 @@ import sys
 import time
 
 BENCHES = ["table1", "table2", "fig3", "fig4", "gram_ablation",
-           "robustness", "population", "serving", "roofline", "microbench"]
+           "robustness", "population", "microbench"]
 _MODULES = {
     "table1": "table1_performance",
     "table2": "table2_scalability",
@@ -27,8 +27,6 @@ _MODULES = {
     "gram_ablation": "gram_ablation",
     "robustness": "robustness",
     "population": "population_scaling",
-    "serving": "serving",
-    "roofline": "roofline",
     "microbench": "microbench",
 }
 

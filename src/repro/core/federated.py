@@ -162,6 +162,14 @@ from repro.optim.adamw import adamw, apply_updates
 from repro.sharding import partition as shard_part
 from repro.sharding.rules import TRAIN_RULES
 
+# Host spans of a round, ``fed.<step>`` with ``round=<n>`` (and ``cohort=<c>``
+# where per cohort), written into a running profiler trace on the device
+# trace's clock; without a trace each costs about a microsecond.  The traced
+# functions name their layers with ``jax.named_scope`` (``device_phase`` ⊃
+# ``ccl``/``amt``, ``channel``, ``mma``, ``server_phase``, ``redistribute``),
+# which reaches the device ops' metadata only.
+_span = jax.profiler.TraceAnnotation
+
 
 # Shared protocol-gating predicates.  Every engine MUST gate the same phase
 # on the same predicate — a bare ``cfg.use_seccl`` in one engine and
@@ -790,55 +798,56 @@ class FederatedRunner:
         reuse the warm traces.  Called exactly once at the top of every
         engine's round; fault-free full-participation runs keep the static
         init-time weights and pay nothing."""
-        cfg = self.cfg
-        rnd = self._round_idx
-        self._round_idx += 1
-        self._rnd_no = rnd
-        ids = None
-        if self._schedule is not None:
-            self._rnd_locals = self._schedule.round_locals(rnd)
-            self._rnd_ids = ids = np.concatenate([
-                off + loc for off, loc in zip(self.spec.offsets,
-                                              self._rnd_locals)])
-            self._rnd_scale = (self._attack_scale[ids]
-                               if self._attack_scale is not None else None)
-        if self._faults is None:
-            if ids is None:
+        with _span("fed.begin", round=self._round_idx):
+            cfg = self.cfg
+            rnd = self._round_idx
+            self._round_idx += 1
+            self._rnd_no = rnd
+            ids = None
+            if self._schedule is not None:
+                self._rnd_locals = self._schedule.round_locals(rnd)
+                self._rnd_ids = ids = np.concatenate([
+                    off + loc for off, loc in zip(self.spec.offsets,
+                                                  self._rnd_locals)])
+                self._rnd_scale = (self._attack_scale[ids]
+                                   if self._attack_scale is not None else None)
+            if self._faults is None:
+                if ids is None:
+                    return
+                # sampler without faults: weights renormalized over the
+                # sampled set (the identity sampler reproduces the static
+                # init-time weights bit-for-bit); presence stays None so the
+                # phase functions keep their mask-free traces
+                if cfg.use_mma and cfg.mode == "mlecs":
+                    w = mma.sampled_weights(self._mod_counts, ids)
+                else:
+                    w = jnp.ones((len(ids),)) / len(ids)
+                self._rnd_weights = np.array(w, np.float32)
                 return
-            # sampler without faults: weights renormalized over the
-            # sampled set (the identity sampler reproduces the static
-            # init-time weights bit-for-bit); presence stays None so the
-            # phase functions keep their mask-free traces
+            present, ontime = self._faults.round_masks(rnd)
+            if ids is not None:
+                present = present[ids].copy()
+                ontime = ontime[ids].copy()
+                if not bool((present & ontime).any()):
+                    # a sampled set whose every member failed must not push an
+                    # all-zero weight vector through the server landing (it
+                    # would zero the server SLM's LoRA); resurrect one member
+                    # — its upload equals its pre-round params, so the
+                    # aggregate is stale-but-sane
+                    present[0] = ontime[0] = True
+            contrib = present & ontime
             if cfg.use_mma and cfg.mode == "mlecs":
-                w = mma.sampled_weights(self._mod_counts, ids)
-            else:
-                w = jnp.ones((len(ids),)) / len(ids)
-            self._rnd_weights = np.array(w, np.float32)
-            return
-        present, ontime = self._faults.round_masks(rnd)
-        if ids is not None:
-            present = present[ids].copy()
-            ontime = ontime[ids].copy()
-            if not bool((present & ontime).any()):
-                # a sampled set whose every member failed must not push an
-                # all-zero weight vector through the server landing (it
-                # would zero the server SLM's LoRA); resurrect one member
-                # — its upload equals its pre-round params, so the
-                # aggregate is stale-but-sane
-                present[0] = ontime[0] = True
-        contrib = present & ontime
-        if cfg.use_mma and cfg.mode == "mlecs":
-            if ids is None:
-                w = mma.aggregation_weights(self._mod_counts,
+                if ids is None:
+                    w = mma.aggregation_weights(self._mod_counts,
+                                                present=contrib)
+                else:
+                    w = mma.sampled_weights(self._mod_counts, ids,
                                             present=contrib)
             else:
-                w = mma.sampled_weights(self._mod_counts, ids,
-                                        present=contrib)
-        else:
-            w = contrib.astype(np.float32) / max(int(contrib.sum()), 1)
-        self._rnd_present = present
-        self._rnd_contrib = contrib
-        self._rnd_weights = np.array(w, np.float32)
+                w = contrib.astype(np.float32) / max(int(contrib.sum()), 1)
+            self._rnd_present = present
+            self._rnd_contrib = contrib
+            self._rnd_weights = np.array(w, np.float32)
 
     def _active_weights(self) -> np.ndarray:
         """This round's globally-normalized weights as host numpy (the
@@ -898,26 +907,27 @@ class FederatedRunner:
         PRESENT member's compressed upload (stragglers transmit too —
         late, weight 0 — but offline clients send nothing) plus one
         multicast downlink payload.  Standalone rounds move nothing."""
-        if self.cfg.mode == "standalone":
-            self.comm_log.append(
-                {"round": self._rnd_no, "uplink": 0, "downlink": 0})
-            return
-        up = up_dense = up_f32 = down = 0
-        for rt in self._cohorts:
-            n = rt.work_n
-            if self._rnd_present is not None:
-                n = int(np.array(
-                    self._rnd_present[rt.work_slice]).sum())
-            up += n * rt.uplink_client_bytes
-            up_dense += n * rt.dense_client_bytes
-            up_f32 += n * rt.f32_client_bytes
-            down += rt.downlink_bytes
-        self._bytes_up += up
-        self._bytes_up_dense += up_dense
-        self._bytes_up_f32 += up_f32
-        self._bytes_down += down
-        self.comm_log.append({"round": self._rnd_no, "uplink": int(up),
-                              "downlink": int(down)})
+        with _span("fed.scatter", round=self._rnd_no):
+            if self.cfg.mode == "standalone":
+                self.comm_log.append(
+                    {"round": self._rnd_no, "uplink": 0, "downlink": 0})
+                return
+            up = up_dense = up_f32 = down = 0
+            for rt in self._cohorts:
+                n = rt.work_n
+                if self._rnd_present is not None:
+                    n = int(np.array(
+                        self._rnd_present[rt.work_slice]).sum())
+                up += n * rt.uplink_client_bytes
+                up_dense += n * rt.dense_client_bytes
+                up_f32 += n * rt.f32_client_bytes
+                down += rt.downlink_bytes
+            self._bytes_up += up
+            self._bytes_up_dense += up_dense
+            self._bytes_up_f32 += up_f32
+            self._bytes_down += down
+            self.comm_log.append({"round": self._rnd_no, "uplink": int(up),
+                                  "downlink": int(down)})
 
     @property
     def comm_stats(self) -> Dict:
@@ -1004,28 +1014,51 @@ class FederatedRunner:
     def _device_chain(self, ccl_step, amt_step, params, opt_state,
                       anchor_llm, gref, pub_steps, priv_steps):
         """(1)+(2) for one cohort: anchors + CCL scan, then the AMT scan —
-        traced inside the fused round or a per-cohort device phase."""
+        traced inside the fused round or a per-cohort device phase, under
+        the scopes ``device_phase/ccl`` and ``device_phase/amt``."""
         cfg = self.cfg
         llm = self.llm
-        if _do_ccl(cfg):
-            def ccl_body(carry, batch):
-                p, o = carry
-                anchor = ccl_lib.stacked_server_anchors(
-                    anchor_llm, llm,
-                    dict(batch, modality_mask=jnp.ones_like(
-                        batch["modality_mask"])))
-                p, o, _ = ccl_step(p, o, batch, anchor)
-                return (p, o), None
-            (params, opt_state), _ = jax.lax.scan(
-                ccl_body, (params, opt_state), pub_steps)
+
+        def ccl_body(carry, batch):
+            p, o = carry
+            anchor = ccl_lib.stacked_server_anchors(
+                anchor_llm, llm,
+                dict(batch, modality_mask=jnp.ones_like(
+                    batch["modality_mask"])))
+            p, o, _ = ccl_step(p, o, batch, anchor)
+            return (p, o), None
 
         def amt_body(carry, batch):
             p, o = carry
             p, o, _ = amt_step(p, o, batch, None, gref)
             return (p, o), None
-        (params, opt_state), _ = jax.lax.scan(
-            amt_body, (params, opt_state), priv_steps)
+
+        with jax.named_scope("device_phase"):
+            if _do_ccl(cfg):
+                with jax.named_scope("ccl"):
+                    (params, opt_state), _ = jax.lax.scan(
+                        ccl_body, (params, opt_state), pub_steps)
+            with jax.named_scope("amt"):
+                (params, opt_state), _ = jax.lax.scan(
+                    amt_body, (params, opt_state), priv_steps)
         return params, opt_state
+
+    def _seccl_scan(self, server_llm, server_slm, llm_opt, slm_opt, steps):
+        """(4) SE-CCL: the joint server step scanned over ``steps``, under
+        the scope ``server_phase`` — traced inside the fused round or the
+        split schedule's server phase."""
+        se_step = self._se_step_raw
+
+        def se_body(carry, batch):
+            s_llm, s_slm, o_llm, o_slm = carry
+            s_llm, s_slm, o_llm, o_slm, _ = se_step(
+                s_llm, s_slm, o_llm, o_slm, batch)
+            return (s_llm, s_slm, o_llm, o_slm), None
+
+        with jax.named_scope("server_phase"):
+            carry, _ = jax.lax.scan(
+                se_body, (server_llm, server_slm, llm_opt, slm_opt), steps)
+        return carry
 
     def _cohort_delivery(self, rt: _Cohort, down: Dict, own_avg: Dict
                          ) -> Dict:
@@ -1065,7 +1098,7 @@ class FederatedRunner:
         cfg = self.cfg
         (rt,) = self._cohorts
         ccl_step, amt_step = self._make_device_steps(rt)
-        se_step = self._se_step_raw
+        seccl_scan = self._seccl_scan
         do_seccl = _do_seccl(cfg)
         with_faults = self._faults is not None
         chan = self.channel
@@ -1125,23 +1158,27 @@ class FederatedRunner:
             # residuals advance only for clients that actually transmitted
             # (the same presence mask that froze their training).
             if not chan.is_identity:
-                dec, new_cs = chan.roundtrip(
-                    uploads.trainable,
-                    chan_states[0] if chan.stateful else None, rnd)
+                with jax.named_scope("channel"):
+                    dec, new_cs = chan.roundtrip(
+                        uploads.trainable,
+                        chan_states[0] if chan.stateful else None, rnd)
                 if chan.stateful:
                     if with_faults:
                         new_cs = _where_clients(present[0], new_cs,
                                                 chan_states[0])
                     chan_states = (new_cs,)
                 uploads = lora.StackedClients(dec)
-            agg = mma.aggregate_stacked(uploads, weights[0])
+            with jax.named_scope("mma"):
+                agg = mma.aggregate_stacked(uploads, weights[0])
 
             if cfg.mode == "fedavg":
                 # Multi-FedAvg: broadcast the average straight back
                 # (through the downlink channel — one multicast payload)
-                rx = chan.roundtrip_tree(agg, rnd)
-                p = deliver(p, uploads, rx,
-                            present[0] if with_faults else None)
+                with jax.named_scope("channel"):
+                    rx = chan.roundtrip_tree(agg, rnd)
+                with jax.named_scope("redistribute"):
+                    p = deliver(p, uploads, rx,
+                                present[0] if with_faults else None)
                 return (post_amt, ((p, o),), server_llm, server_slm,
                         server_llm_opt, server_slm_opt, (rx,), chan_states)
 
@@ -1149,23 +1186,19 @@ class FederatedRunner:
 
             # (4) SE-CCL on the server
             if do_seccl:
-                def se_body(carry, batch):
-                    s_llm, s_slm, o_llm, o_slm = carry
-                    s_llm, s_slm, o_llm, o_slm, _ = se_step(
-                        s_llm, s_slm, o_llm, o_slm, batch)
-                    return (s_llm, s_slm, o_llm, o_slm), None
-                (server_llm, server_slm, server_llm_opt, server_slm_opt), _ \
-                    = jax.lax.scan(
-                        se_body,
-                        (server_llm, server_slm, server_llm_opt,
-                         server_slm_opt), server_steps)
+                (server_llm, server_slm, server_llm_opt,
+                 server_slm_opt) = seccl_scan(
+                    server_llm, server_slm, server_llm_opt, server_slm_opt,
+                    server_steps)
 
             # (5) redistribute server-SLM LoRA to every device (broadcast
             # through the downlink channel; clients see the decoded tree)
-            down = chan.roundtrip_tree(
-                lora.partition(server_slm, lora.is_lora_leaf), rnd)
-            p = deliver(p, uploads, down,
-                        present[0] if with_faults else None)
+            with jax.named_scope("channel"):
+                down = chan.roundtrip_tree(
+                    lora.partition(server_slm, lora.is_lora_leaf), rnd)
+            with jax.named_scope("redistribute"):
+                p = deliver(p, uploads, down,
+                            present[0] if with_faults else None)
             return (post_amt, ((p, o),), server_llm, server_slm,
                     server_llm_opt, server_slm_opt, (down,), chan_states)
 
@@ -1273,41 +1306,42 @@ class FederatedRunner:
         spec = self.spec
         rnd = self._assemble_idx
         self._assemble_idx += 1
-        locals_ = (self._schedule.round_locals(rnd)
-                   if self._schedule is not None else None)
-        pubs, privs = [], []
-        for rt in self._cohorts:
-            if locals_ is None:
-                members = range(rt.offset, rt.offset + rt.n)
-            else:
-                members = [rt.offset + int(i) for i in locals_[rt.idx]]
-            pub = self._streams.gather_steps(
-                [f"pub/{j}" for j in members],
-                spec.cohort_steps_ccl(rt.idx)) if _do_ccl(cfg) else None
-            priv = self._streams.gather_steps(
-                [f"priv/{j}" for j in members],
-                spec.cohort_steps_amt(rt.idx))
-            m = self._mesh_for(rt.idx)
-            if m is not None:
-                def put(tree, _m=m):
-                    return jax.device_put(
-                        tree, shard_part.stacked_client_shardings(
-                            tree, _m, TRAIN_RULES, axis=1))
-                pub = put(pub) if pub is not None else None
-                priv = put(priv)
-            pubs.append(pub)
-            privs.append(priv)
-        server = self._streams.stack_steps("server", cfg.server_steps) \
-            if _do_seccl(cfg) else None
-        if server is not None:
-            srv_dev = getattr(self, "_server_device", None)
-            if srv_dev is not None:
-                server = jax.device_put(server, srv_dev)
-            elif self.mesh is not None:
-                server = jax.device_put(
-                    server,
-                    shard_part.replicated_shardings(server, self.mesh))
-        return tuple(pubs), tuple(privs), server
+        with _span("fed.assemble", round=rnd):
+            locals_ = (self._schedule.round_locals(rnd)
+                       if self._schedule is not None else None)
+            pubs, privs = [], []
+            for rt in self._cohorts:
+                if locals_ is None:
+                    members = range(rt.offset, rt.offset + rt.n)
+                else:
+                    members = [rt.offset + int(i) for i in locals_[rt.idx]]
+                pub = self._streams.gather_steps(
+                    [f"pub/{j}" for j in members],
+                    spec.cohort_steps_ccl(rt.idx)) if _do_ccl(cfg) else None
+                priv = self._streams.gather_steps(
+                    [f"priv/{j}" for j in members],
+                    spec.cohort_steps_amt(rt.idx))
+                m = self._mesh_for(rt.idx)
+                if m is not None:
+                    def put(tree, _m=m):
+                        return jax.device_put(
+                            tree, shard_part.stacked_client_shardings(
+                                tree, _m, TRAIN_RULES, axis=1))
+                    pub = put(pub) if pub is not None else None
+                    priv = put(priv)
+                pubs.append(pub)
+                privs.append(priv)
+            server = self._streams.stack_steps("server", cfg.server_steps) \
+                if _do_seccl(cfg) else None
+            if server is not None:
+                srv_dev = getattr(self, "_server_device", None)
+                if srv_dev is not None:
+                    server = jax.device_put(server, srv_dev)
+                elif self.mesh is not None:
+                    server = jax.device_put(
+                        server,
+                        shard_part.replicated_shardings(server, self.mesh))
+            return tuple(pubs), tuple(privs), server
 
     # ------------------------------------------------------------------
     # population layer: gather each round's sampled working set from the
@@ -1351,18 +1385,19 @@ class FederatedRunner:
         when it belongs to this round."""
         if self._schedule is None or not self._stacked:
             return
-        host = None
-        box = getattr(self, "_staged_gather", None)
-        if box is not None:
-            self._staged_gather = None
-            box["thread"].join()
-            if box["err"] is not None:
-                raise box["err"]
-            if box["rnd"] == self._rnd_no:
-                host = box["out"]
-        if host is None:
-            host = self._gather_host(self._rnd_locals)
-        self._install_working_set(host)
+        with _span("fed.begin", round=self._rnd_no):
+            host = None
+            box = getattr(self, "_staged_gather", None)
+            if box is not None:
+                self._staged_gather = None
+                box["thread"].join()
+                if box["err"] is not None:
+                    raise box["err"]
+                if box["rnd"] == self._rnd_no:
+                    host = box["out"]
+            if host is None:
+                host = self._gather_host(self._rnd_locals)
+            self._install_working_set(host)
 
     def _scatter_working_set(self) -> None:
         """Write the trained working set back to the registered population
@@ -1370,13 +1405,14 @@ class FederatedRunner:
         optimizer state — exactly what :meth:`__init__` registered)."""
         if self._schedule is None or not self._stacked:
             return
-        for rt in self._cohorts:
-            ids = [rt.offset + int(i) for i in self._rnd_locals[rt.idx]]
-            entry = {"train": lora.partition(rt.stacked_params),
-                     "opt": rt.stacked_opt}
-            if self.channel.stateful:
-                entry["chan"] = rt.chan_state
-            self._store.scatter(ids, entry)
+        with _span("fed.scatter", round=self._rnd_no):
+            for rt in self._cohorts:
+                ids = [rt.offset + int(i) for i in self._rnd_locals[rt.idx]]
+                entry = {"train": lora.partition(rt.stacked_params),
+                         "opt": rt.stacked_opt}
+                if self.channel.stateful:
+                    entry["chan"] = rt.chan_state
+                self._store.scatter(ids, entry)
 
     def _stage_next_gather(self) -> None:
         """Overlap engine: start the NEXT round's store gather on a daemon
@@ -1444,21 +1480,22 @@ class FederatedRunner:
         through untouched (the pre-channel graph, bit for bit)."""
         if self.channel.is_identity:
             return payloads
-        cfg = self.cfg
-        out = []
-        for rt, pl in zip(self._cohorts, payloads):
-            if self.channel.stateful:
-                rt.chan_state = pl["state"]
-            dec = self.channel.decode(pl["enc"], rt.up_like)
-            if cfg.robust != "mean":
-                out.append(dec)
-            elif self._homogeneous:
-                out.append(mma.aggregate_stacked(
-                    lora.StackedClients(dec), self._weights_for(rt)))
-            else:
-                out.append(mma.partial_aggregate_stacked(
-                    lora.StackedClients(dec), self._weights_for(rt)))
-        return out
+        with _span("fed.decode", round=self._rnd_no):
+            cfg = self.cfg
+            out = []
+            for rt, pl in zip(self._cohorts, payloads):
+                if self.channel.stateful:
+                    rt.chan_state = pl["state"]
+                dec = self.channel.decode(pl["enc"], rt.up_like)
+                if cfg.robust != "mean":
+                    out.append(dec)
+                elif self._homogeneous:
+                    out.append(mma.aggregate_stacked(
+                        lora.StackedClients(dec), self._weights_for(rt)))
+                else:
+                    out.append(mma.partial_aggregate_stacked(
+                        lora.StackedClients(dec), self._weights_for(rt)))
+            return out
 
     def _combine_payloads(self, payloads, device=None):
         """Fold the cohorts' device-phase payloads into the server-bound
@@ -1471,18 +1508,19 @@ class FederatedRunner:
         Under ``robust != "mean"`` the payloads are instead RAW stacked
         uploads and the reduction routes to :meth:`_robust_combine`.
         Returns ``(agg, own_avgs)``."""
-        if self.cfg.robust != "mean":
-            return self._robust_combine(payloads, device=device)
-        if self._homogeneous:
-            return payloads[0], ({},)
-        own_avgs = self._own_avgs(payloads)
-        partials = payloads if device is None else [
-            jax.device_put(p, device) for p in payloads]
-        agg = mma.combine_cohort_partials(
-            partials, [rt.shared for rt in self._cohorts],
-            [self._w_total_for(rt) for rt in self._cohorts],
-            self._server_lora_dtypes)
-        return agg, own_avgs
+        with _span("fed.combine", round=self._rnd_no):
+            if self.cfg.robust != "mean":
+                return self._robust_combine(payloads, device=device)
+            if self._homogeneous:
+                return payloads[0], ({},)
+            own_avgs = self._own_avgs(payloads)
+            partials = payloads if device is None else [
+                jax.device_put(p, device) for p in payloads]
+            agg = mma.combine_cohort_partials(
+                partials, [rt.shared for rt in self._cohorts],
+                [self._w_total_for(rt) for rt in self._cohorts],
+                self._server_lora_dtypes)
+            return agg, own_avgs
 
     def _robust_combine(self, payloads, device=None):
         """The robust counterpart of :meth:`_combine_payloads`:
@@ -1531,30 +1569,32 @@ class FederatedRunner:
         the server phase, violating the no-retrace invariant."""
         if self._rnd_present is None or self._homogeneous:
             return agg
-        missing = [k for rt in self._cohorts for k in rt.shared
-                   if k not in agg]
-        if missing:
-            cur = lora.partition(self.server_slm, lora.is_lora_leaf)
-            agg = dict(agg)
-            for k in missing:
-                # a copy: the server phase may donate the server SLM
-                agg[k] = jnp.copy(cur[k])
-        return agg
+        with _span("fed.combine", round=self._rnd_no):
+            missing = [k for rt in self._cohorts for k in rt.shared
+                       if k not in agg]
+            if missing:
+                cur = lora.partition(self.server_slm, lora.is_lora_leaf)
+                agg = dict(agg)
+                for k in missing:
+                    # a copy: the server phase may donate the server SLM
+                    agg[k] = jnp.copy(cur[k])
+            return agg
 
     def _apply_deliveries(self, down, own_avgs) -> None:
         """Alg. 1 step 5 across cohorts: splice each cohort's delivery
         (shared subset from ``down`` + its own-key averages) into its
         stacked tree and remember it as the prox/redistribution
         reference."""
-        for c, rt in enumerate(self._cohorts):
-            delivery = self._cohort_delivery(rt, down, own_avgs[c])
-            # downlink channel: one multicast payload per cohort; clients
-            # (and the prox reference) see the DECODED tree
-            delivery = self.channel.roundtrip_tree(delivery, self._rnd_no)
-            delivery = self._to_client_placement(rt, delivery)
-            rt.stacked_params = self._redistribute(
-                rt, rt.stacked_params, delivery)
-            rt.last_global = delivery
+        with _span("fed.deliver", round=self._rnd_no):
+            for c, rt in enumerate(self._cohorts):
+                delivery = self._cohort_delivery(rt, down, own_avgs[c])
+                # downlink channel: one multicast payload per cohort; clients
+                # (and the prox reference) see the DECODED tree
+                delivery = self.channel.roundtrip_tree(delivery, self._rnd_no)
+                delivery = self._to_client_placement(rt, delivery)
+                rt.stacked_params = self._redistribute(
+                    rt, rt.stacked_params, delivery)
+                rt.last_global = delivery
 
     def _make_overlap_phases(self):
         """Build the pipelined phase functions.
@@ -1583,7 +1623,7 @@ class FederatedRunner:
         invalidate a live reference.
         """
         cfg = self.cfg
-        se_step = self._se_step_raw
+        seccl_scan = self._seccl_scan
         do_seccl = _do_seccl(cfg)
         standalone = cfg.mode == "standalone"
         multi = not self._homogeneous
@@ -1630,9 +1670,10 @@ class FederatedRunner:
                     # and the runner decodes it eagerly before any
                     # reduction (see _decode_payloads — order-statistic
                     # robust reductions need dense per-client values)
-                    enc, new_state = chan.encode(
-                        uploads.trainable,
-                        chan_state if chan.stateful else None, rnd)
+                    with jax.named_scope("channel"):
+                        enc, new_state = chan.encode(
+                            uploads.trainable,
+                            chan_state if chan.stateful else None, rnd)
                     if chan.stateful and with_faults:
                         new_state = _where_clients(present, new_state,
                                                    chan_state)
@@ -1647,12 +1688,14 @@ class FederatedRunner:
                     return stacked_params, stacked_opt, uploads.trainable
                 if not multi:
                     # legacy single-cohort: the payload IS the aggregate
-                    agg = mma.aggregate_stacked(uploads, weights)
+                    with jax.named_scope("mma"):
+                        agg = mma.aggregate_stacked(uploads, weights)
                     return stacked_params, stacked_opt, agg
                 # heterogeneous: only the f32 partial leaves the jit — the
                 # own-key averages and the cross-cohort combine happen
                 # eagerly so every engine rounds them identically
-                partial = mma.partial_aggregate_stacked(uploads, weights)
+                with jax.named_scope("mma"):
+                    partial = mma.partial_aggregate_stacked(uploads, weights)
                 return stacked_params, stacked_opt, partial
 
             return jax.jit(device_phase, donate_argnums=donate_dev)
@@ -1661,16 +1704,10 @@ class FederatedRunner:
                          server_slm_opt, agg, server_steps):
             server_slm = lora.combine(server_slm, agg)
             if do_seccl:
-                def se_body(carry, batch):
-                    s_llm, s_slm, o_llm, o_slm = carry
-                    s_llm, s_slm, o_llm, o_slm, _ = se_step(
-                        s_llm, s_slm, o_llm, o_slm, batch)
-                    return (s_llm, s_slm, o_llm, o_slm), None
-                (server_llm, server_slm, server_llm_opt, server_slm_opt), _ \
-                    = jax.lax.scan(
-                        se_body,
-                        (server_llm, server_slm, server_llm_opt,
-                         server_slm_opt), server_steps)
+                (server_llm, server_slm, server_llm_opt,
+                 server_slm_opt) = seccl_scan(
+                    server_llm, server_slm, server_llm_opt, server_slm_opt,
+                    server_steps)
             down = lora.partition(server_slm, lora.is_lora_leaf)
             # SE-CCL trains the LLM's LoRA *and* connector; anchors read the
             # connector, so the anchor download is the full trainable set
@@ -1736,11 +1773,12 @@ class FederatedRunner:
         for c, rt in enumerate(self._cohorts):
             # stale-anchor model: frozen base + last downloaded trainables
             anchor_llm = lora.combine(rt.anchor_base, rt.anchor_tr)
-            post_amt, rt.stacked_opt, payload = self._device_phase_fns[c](
-                rt.stacked_params, rt.stacked_opt, anchor_llm,
-                rt.last_global, self._weights_for(rt), pubs[c], privs[c],
-                self._present_for(rt), self._scale_for(rt),
-                self._chan_state_for(rt), self._chan_rnd())
+            with _span("fed.dispatch", round=self._rnd_no, cohort=c):
+                post_amt, rt.stacked_opt, payload = self._device_phase_fns[c](
+                    rt.stacked_params, rt.stacked_opt, anchor_llm,
+                    rt.last_global, self._weights_for(rt), pubs[c], privs[c],
+                    self._present_for(rt), self._scale_for(rt),
+                    self._chan_state_for(rt), self._chan_rnd())
             rt.stacked_params = post_amt
             post_amts.append(post_amt)
             payloads.append(payload)
@@ -1768,10 +1806,13 @@ class FederatedRunner:
         else:
             agg_srv = jax.device_put(self._stable_agg(agg),
                                      self._server_device)
-            (self.server_llm, self.server_slm, self.server_llm_opt,
-             self.server_slm_opt, down, anchor_tr) = self._server_phase_fn(
-                self.server_llm, self.server_slm, self.server_llm_opt,
-                self.server_slm_opt, agg_srv, server)
+            with _span("fed.server_phase", round=self._rnd_no):
+                (self.server_llm, self.server_slm, self.server_llm_opt,
+                 self.server_slm_opt, down, anchor_tr) = \
+                    self._server_phase_fn(
+                        self.server_llm, self.server_slm,
+                        self.server_llm_opt, self.server_slm_opt, agg_srv,
+                        server)
             self._srv_q.append((down, anchor_tr, own_avgs))
 
         if len(self._srv_q) > cfg.staleness:
@@ -1820,11 +1861,12 @@ class FederatedRunner:
         :meth:`evaluate_server` / :meth:`evaluate` afterwards to measure
         the eval phases separately.
         """
-        if self.engine == "vectorized":
-            return self._run_round_vectorized(evaluate)
-        if self.engine == "overlap":
-            return self._run_round_overlap(evaluate)
-        return self._run_round_loop(evaluate)
+        with _span("fed.round", round=self._round_idx):
+            if self.engine == "vectorized":
+                return self._run_round_vectorized(evaluate)
+            if self.engine == "overlap":
+                return self._run_round_overlap(evaluate)
+            return self._run_round_loop(evaluate)
 
     # ------------------------------------------------------------------
     def _run_round_vectorized(self, evaluate: bool = True) -> Dict:
@@ -1843,12 +1885,13 @@ class FederatedRunner:
                if self._rnd_scale is not None else None)
         css = (tuple(rt.chan_state for rt in self._cohorts)
                if self.channel.stateful else None)
-        (post_amt, states, self.server_llm, self.server_slm,
-         self.server_llm_opt, self.server_slm_opt, lgs,
-         css) = self._round_fn(
-            states, self.server_llm, self.server_slm, self.server_llm_opt,
-            self.server_slm_opt, lgs, ws, pubs, privs, server, pres, scs,
-            css, self._chan_rnd())
+        with _span("fed.dispatch", round=self._rnd_no):
+            (post_amt, states, self.server_llm, self.server_slm,
+             self.server_llm_opt, self.server_slm_opt, lgs,
+             css) = self._round_fn(
+                states, self.server_llm, self.server_slm,
+                self.server_llm_opt, self.server_slm_opt, lgs, ws, pubs,
+                privs, server, pres, scs, css, self._chan_rnd())
         for rt, (p, o), lg in zip(self._cohorts, states, lgs):
             rt.stacked_params, rt.stacked_opt, rt.last_global = p, o, lg
         post_amt = tuple(lora.combine(rt.stacked_params, pa)
@@ -1876,11 +1919,12 @@ class FederatedRunner:
         pubs, privs, server = self._assemble_round()
         payloads, post_amts = [], []
         for c, rt in enumerate(self._cohorts):
-            post_amt, rt.stacked_opt, payload = self._device_phase_fns[c](
-                rt.stacked_params, rt.stacked_opt, self.server_llm,
-                rt.last_global, self._weights_for(rt), pubs[c], privs[c],
-                self._present_for(rt), self._scale_for(rt),
-                self._chan_state_for(rt), self._chan_rnd())
+            with _span("fed.dispatch", round=self._rnd_no, cohort=c):
+                post_amt, rt.stacked_opt, payload = self._device_phase_fns[c](
+                    rt.stacked_params, rt.stacked_opt, self.server_llm,
+                    rt.last_global, self._weights_for(rt), pubs[c], privs[c],
+                    self._present_for(rt), self._scale_for(rt),
+                    self._chan_state_for(rt), self._chan_rnd())
             rt.stacked_params = post_amt
             post_amts.append(post_amt)
             payloads.append(payload)
@@ -1891,10 +1935,13 @@ class FederatedRunner:
             if cfg.mode == "fedavg":
                 self._apply_deliveries(agg, own_avgs)
             else:
-                (self.server_llm, self.server_slm, self.server_llm_opt,
-                 self.server_slm_opt, down, _) = self._server_phase_fn(
-                    self.server_llm, self.server_slm, self.server_llm_opt,
-                    self.server_slm_opt, self._stable_agg(agg), server)
+                agg = self._stable_agg(agg)
+                with _span("fed.server_phase", round=self._rnd_no):
+                    (self.server_llm, self.server_slm, self.server_llm_opt,
+                     self.server_slm_opt, down, _) = self._server_phase_fn(
+                        self.server_llm, self.server_slm,
+                        self.server_llm_opt, self.server_slm_opt, agg,
+                        server)
                 self._apply_deliveries(down, own_avgs)
         self._scatter_working_set()
         self._commit_comm()

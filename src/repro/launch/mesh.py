@@ -91,7 +91,16 @@ def use_compile_cache() -> str:
     an entry only under the directory that wrote it.  Call it once, before
     the first compile, from every entry point (scripts, benchmarks, the
     test ``conftest.py``).
+
+    An entry's key includes the ops' metadata: by default JAX strips it,
+    and a program that differs from a cached one only in its named scopes
+    (which a profiler trace attributes device time by) would load the
+    other's executable and show the other's names.  Locations keep only
+    the innermost source frame (with the full scope path), so the key does
+    not depend on the caller.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -46,6 +46,12 @@ from repro.models.model import ModelBundle, build_model
 
 _RID = itertools.count()
 
+# Host spans on the profiler's clock (about a microsecond each while no trace
+# runs): ``serve.tick`` ⊃ ``serve.admit`` (⊃ ``serve.prefill``,
+# ``serve.insert``), ``serve.step``, ``serve.finish``.  Their arguments are
+# host state; none reads a device array.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class EngineConfig:
@@ -77,8 +83,10 @@ class Request:
     frontend_embeds: Optional[np.ndarray] = None   # (T, F) vlm/encdec stub
     prefix_embeds: Optional[np.ndarray] = None     # (P, d) ML-ECS soft prompt
     rid: int = dataclasses.field(default_factory=lambda: next(_RID))
-    # filled by the engine
+    # filled by the engine, on the ``time.perf_counter`` clock
     t_submit: float = 0.0
+    t_admit: float = 0.0             # its admission starts
+    t_first: float = 0.0             # its first token is in place
     t_done: float = 0.0
     out: Optional[np.ndarray] = None
 
@@ -116,7 +124,11 @@ class ServingEngine:
         self._free_slots: List[int] = list(range(ec.n_slots))
         self._slot_req: Dict[int, Request] = {}
         self._slot_pages: Dict[int, List[int]] = {}
+        self.n_ticks = 0
         self.n_steps = 0
+        self.n_admitted = 0
+        self.n_page_waits = 0
+        self.slot_steps = 0
         self._step = jax.jit(self._make_step())
         self._prefill = jax.jit(bundle.prefill_paged)   # one trace per bucket
         self._insert = jax.jit(bundle.insert_paged)     # one per page count
@@ -216,11 +228,27 @@ class ServingEngine:
                     f"request needs {ctx} cache entries > block-table "
                     f"capacity {ec.max_pages_per_seq * ec.page_size}")
             if n_req > len(self._free_pages):
+                self.n_page_waits += 1
                 break                       # wait for an eviction
             self.pending.popleft()
             slot = self._free_slots.pop()
             pages = [self._free_pages.pop() for _ in range(n_req)]
+            req.t_admit = time.perf_counter()
+            with _span("serve.admit", rid=req.rid, slot=slot, pages=n_req,
+                       bucket=S_pad, queued_us=round(
+                           (req.t_admit - req.t_submit) * 1e6)):
+                self._admit(req, slot, pages, S, P, S_pad)
+            admitted += 1
+        self.n_admitted += admitted
+        return admitted
 
+    def _admit(self, req: Request, slot: int, pages: List[int], S: int,
+               P: int, S_pad: int) -> None:
+        """Prefill ``req`` (prompt of ``S`` tokens padded to ``S_pad``, after
+        ``P`` prefix entries), sample its first token, and place it in decode
+        ``slot`` over ``pages`` — or finish it at once."""
+        ec = self.econf
+        with _span("serve.prefill"):
             toks = np.zeros((1, S_pad), np.int32)
             toks[0, :S] = req.tokens
             batch = {"tokens": jnp.asarray(toks)}
@@ -232,15 +260,15 @@ class ServingEngine:
             last, pack, _ = self._prefill(self.params, batch, jnp.int32(S))
             tok0 = self._sample_host(last[0])
 
-            if req.max_new <= 1 or tok0 == ec.eos_id:
-                self._free_pages.extend(pages)
-                self._free_slots.append(slot)
-                req.out = np.array([tok0], np.int32)
-                req.t_done = time.perf_counter()
-                self.finished[req.rid] = req
-                admitted += 1
-                continue
+        if req.max_new <= 1 or tok0 == ec.eos_id:
+            self._free_pages.extend(pages)
+            self._free_slots.append(slot)
+            req.out = np.array([tok0], np.int32)
+            req.t_first = req.t_done = time.perf_counter()
+            self.finished[req.rid] = req
+            return
 
+        with _span("serve.insert"):
             if self.paged_fam:
                 n_used = paged.pages_for(P + S_pad, ec.page_size)
                 page_ids = jnp.asarray(pages[:n_used], jnp.int32)
@@ -249,7 +277,7 @@ class ServingEngine:
             self.pstate = self._insert(self.pstate, pack, jnp.int32(slot),
                                        page_ids)
             bt_row = np.zeros((ec.max_pages_per_seq,), np.int32)
-            bt_row[:n_req] = pages
+            bt_row[:len(pages)] = pages
             sd = self.sched
             self.sched = dict(
                 sd,
@@ -264,8 +292,7 @@ class ServingEngine:
             )
             self._slot_req[slot] = req
             self._slot_pages[slot] = pages
-            admitted += 1
-        return admitted
+        req.t_first = time.perf_counter()
 
     # ------------------------------------------------------------------
     # the serving loop
@@ -276,17 +303,21 @@ class ServingEngine:
 
     def step_once(self):
         """One jitted decode step + host-side collection of finished slots."""
-        prev_active = np.array(self.sched["active"])
-        self.pstate, self.sched = self._step(self.params, self.pstate,
-                                             self.sched)
+        busy = len(self._slot_req)
+        with _span("serve.step", busy=busy):
+            prev_active = np.array(self.sched["active"])
+            self.pstate, self.sched = self._step(self.params, self.pstate,
+                                                 self.sched)
+            act = np.array(self.sched["active"])
         self.n_steps += 1
-        act = np.array(self.sched["active"])
+        self.slot_steps += busy
         newly = np.nonzero(prev_active & ~act)[0]
         if len(newly):
-            n_out = np.array(self.sched["n_out"])
-            rows = np.array(self.sched["out_buf"][jnp.asarray(newly)])
-            for i, slot in enumerate(newly):
-                self._finish(int(slot), rows[i, :n_out[slot]])
+            with _span("serve.finish"):
+                n_out = np.array(self.sched["n_out"])
+                rows = np.array(self.sched["out_buf"][jnp.asarray(newly)])
+                for i, slot in enumerate(newly):
+                    self._finish(int(slot), rows[i, :n_out[slot]])
 
     def _finish(self, slot: int, tokens):
         req = self._slot_req.pop(slot)
@@ -298,10 +329,31 @@ class ServingEngine:
 
     def tick(self) -> bool:
         """Admit what fits, then decode one step.  Returns ``busy``."""
-        self._try_admit()
-        if self._slot_req:
-            self.step_once()
+        with _span("serve.tick", tick=self.n_ticks):
+            self.n_ticks += 1
+            self._try_admit()
+            if self._slot_req:
+                self.step_once()
         return self.busy
+
+    def stats(self) -> Dict[str, int]:
+        """A snapshot of the scheduler from host state alone (it reads no
+        device array): requests ``queued``; decode slots busy and in all;
+        pages free and in all (page 0, the scratch page, not counted); and,
+        counted since the engine was built, ``admissions``, the admissions
+        deferred because free pages ran short (``admit_waits_pages``, one
+        per tick at most), decode ``steps``, and ``slot_steps``, the busy
+        slots summed over decode steps."""
+        ec = self.econf
+        return {"queued": len(self.pending),
+                "slots_busy": len(self._slot_req),
+                "slots_total": ec.n_slots,
+                "pages_free": len(self._free_pages),
+                "pages_total": ec.n_pages - 1,
+                "admissions": self.n_admitted,
+                "admit_waits_pages": self.n_page_waits,
+                "steps": self.n_steps,
+                "slot_steps": self.slot_steps}
 
     def run(self) -> Dict[int, Request]:
         """Drive everything submitted so far to completion."""
