@@ -272,9 +272,10 @@ def phase_serving(slm, params, *, n_requests: int, seed: int, on_tpu: bool):
         del params
         rids = [kern.submit(t, max_new=m) for t, m in reqs]
         # the state after the first wave of admissions: the decode step
-        # both attention paths are compared on if their tokens differ
+        # both attention paths are compared on if their tokens differ (a
+        # copy: the engine's step donates the pool it is handed)
         kern._try_admit()
-        first = (kern.pstate, kern.sched)
+        first = (jax.tree.map(jnp.copy, kern.pstate), kern.sched)
         done_k = kern.run()
         hlo = kern._step.lower(kern.params, kern.pstate,
                                kern.sched).compile().as_text()

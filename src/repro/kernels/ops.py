@@ -58,15 +58,16 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     return out.transpose(0, 2, 1, 3).reshape(B, Sq, H * D)
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lens, window, *,
-                    use_kernel=None, interpret=None):
+def paged_attention(q, k_pages, v_pages, layer, block_tables, lens, window,
+                    *, use_kernel=None, interpret=None):
     """Decode-mode (Sq=1) attention over a paged KV cache, GQA-aware.
 
-    q: (B, 1, H, D) model layout;  k_pages/v_pages: (P, ps, K, D);
-    block_tables: (B, M) int32 page ids per logical block;  lens: (B,) int32
-    valid entries per slot INCLUDING the newest token (0 = idle slot);
-    window: scalar int32 (layers.BIG_WINDOW = none; may be traced — the
-    per-layer window rides through the model's layer scan).
+    q: (B, 1, H, D) model layout;  k_pages/v_pages: (L, P, ps, K * D), the
+    whole pool of every layer;  layer: scalar int32, the layer to read (may
+    be traced — it rides through the model's layer scan);  block_tables:
+    (B, M) int32 page ids per logical block;  lens: (B,) int32 valid entries
+    per slot INCLUDING the newest token (0 = idle slot);  window: scalar
+    int32 (layers.BIG_WINDOW = none; may be traced too).
 
     Returns (B, 1, H * D).  ``use_kernel`` None = kernel when compiled for
     TPU, pure-jnp gather path elsewhere (the Pallas grid walks one page per
@@ -75,11 +76,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, lens, window, *,
     oracle's twin).
     """
     B, _, H, D = q.shape
-    ps, K = k_pages.shape[1], k_pages.shape[2]
+    ps, K = k_pages.shape[2], k_pages.shape[3] // D
     M = block_tables.shape[1]
 
     def kernel(interp):
-        out = _paged(q, k_pages, v_pages, block_tables, lens, window,
+        out = _paged(q, k_pages, v_pages, layer, block_tables, lens, window,
                      interpret=interp)
         return out.reshape(B, 1, H * D)
 
@@ -87,8 +88,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, lens, window, *,
         # mha math inlined (models.layers imports would cycle)
         G = H // K
         import math as _math
-        k = k_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
-        v = v_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
+        k = k_pages[layer, block_tables].reshape(B, M * ps, K, D) \
+            .astype(jnp.float32)
+        v = v_pages[layer, block_tables].reshape(B, M * ps, K, D) \
+            .astype(jnp.float32)
         qf = q.astype(jnp.float32).reshape(B, K, G, D)
         logits = jnp.einsum("bkgd,bskd->bkgs", qf, k) / _math.sqrt(D)
         qpos = lens[:, None] - 1

@@ -61,19 +61,21 @@ def attention_ref(q, k, v, causal: bool = True,
 # ---------------------------------------------------------------------------
 # paged decode attention
 
-def paged_attention_ref(q, k_pages, v_pages, block_tables, lens,
+def paged_attention_ref(q, k_pages, v_pages, layer, block_tables, lens,
                         window: Optional[int] = None):
-    """Decode-mode oracle.  q: (B,1,H,D);  k_pages/v_pages: (P,ps,K,D);
-    block_tables: (B,M) page ids;  lens: (B,) valid entries incl. the newest
-    token.  KV heads are grouped (GQA); idle slots (len 0) return zeros.
-    Returns (B, 1, H, D)."""
+    """Decode-mode oracle.  q: (B,1,H,D);  k_pages/v_pages: (L,P,ps,K*D),
+    the kv heads of an entry side by side in its row;  layer: the pool's
+    layer to read;  block_tables: (B,M) page ids;  lens: (B,) valid entries
+    incl. the newest token.  KV heads are grouped (GQA); idle slots (len 0)
+    return zeros.  Returns (B, 1, H, D)."""
     B, _, H, D = q.shape
-    ps, K = k_pages.shape[1], k_pages.shape[2]
+    ps, K = k_pages.shape[2], k_pages.shape[3] // D
     M = block_tables.shape[1]
     G = H // K
     # gather each request's logical KV sequence: (B, M*ps, K, D)
-    k = k_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
+    kl, vl = k_pages[layer], v_pages[layer]
+    k = kl[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
+    v = vl[block_tables].reshape(B, M * ps, K, D).astype(jnp.float32)
     qf = q.astype(jnp.float32).reshape(B, K, G, D)
     logits = jnp.einsum("bkgd,bskd->bkgs", qf, k) / math.sqrt(D)
     qpos = lens[:, None] - 1                               # (B,1)

@@ -12,7 +12,10 @@ engine replaces that with the vLLM-style serving loop on top of
   release all inside the jit; no per-token Python dispatch).
 * **Paged KV pool + free-list allocator** — requests own pages, not a
   contiguous region; admission only needs ``ceil(ctx / page_size)`` free
-  pages, and eviction returns them the moment a sequence finishes.
+  pages, and eviction returns them the moment a sequence finishes.  The
+  step and the insert take the pool state donated (:func:`jit_step`,
+  :func:`jit_insert`), so each writes the one pool in place: hold no
+  reference to ``engine.pstate`` across a tick.
 * **Admission control** — pending requests are admitted whenever a slot AND
   enough pages are free; prompts are right-padded to compile buckets for the
   attention families (recurrent families prefill at exact length — padding
@@ -95,6 +98,70 @@ class Request:
         return self.t_done - self.t_submit
 
 
+def init_sched(ec: EngineConfig) -> dict:
+    """The scheduler's device state: one row per decode slot."""
+    return {
+        "block_tables": jnp.zeros((ec.n_slots, ec.max_pages_per_seq),
+                                  jnp.int32),
+        "seq_lens": jnp.zeros((ec.n_slots,), jnp.int32),
+        "active": jnp.zeros((ec.n_slots,), bool),
+        "last_tok": jnp.zeros((ec.n_slots,), jnp.int32),
+        "out_buf": jnp.zeros((ec.n_slots, ec.max_out), jnp.int32),
+        "n_out": jnp.zeros((ec.n_slots,), jnp.int32),
+        "budget": jnp.zeros((ec.n_slots,), jnp.int32),
+        "key": jax.random.key(ec.seed),
+    }
+
+
+def jit_step(bundle: ModelBundle, ec: EngineConfig):
+    """The ONE jitted decode step, ``(params, pstate, sched) -> (pstate,
+    sched)``.  ``pstate`` is donated, so the page pool is written in place;
+    ``sched`` is not (the host reads it between ticks)."""
+    n = ec.n_slots
+
+    def step(params, pstate, sd):
+        logits, pstate = bundle.decode_paged(
+            params, pstate, sd["block_tables"], sd["seq_lens"],
+            sd["last_tok"][:, None], sd["active"], ec.use_kernel)
+        if ec.temperature > 0:
+            key, sub = jax.random.split(sd["key"])
+            tok = jax.random.categorical(sub, logits / ec.temperature,
+                                         axis=-1)
+        else:
+            key, tok = sd["key"], jnp.argmax(logits, axis=-1)
+        tok = tok.astype(jnp.int32)
+        act = sd["active"]
+        row = jnp.arange(n)
+        idx = jnp.minimum(sd["n_out"], ec.max_out - 1)
+        out_buf = sd["out_buf"].at[row, idx].set(
+            jnp.where(act, tok, sd["out_buf"][row, idx]))
+        n_out = sd["n_out"] + act.astype(jnp.int32)
+        seq_lens = sd["seq_lens"] + act.astype(jnp.int32)
+        done = act & ((n_out >= sd["budget"]) | (tok == ec.eos_id))
+        return pstate, {
+            # release: a zeroed row points every future write at the
+            # scratch page; the host frees the physical pages
+            "block_tables": jnp.where(done[:, None], 0,
+                                      sd["block_tables"]),
+            "seq_lens": seq_lens,
+            "active": act & ~done,
+            "last_tok": jnp.where(act, tok, sd["last_tok"]),
+            "out_buf": out_buf,
+            "n_out": n_out,
+            "budget": sd["budget"],
+            "key": key,
+        }
+
+    return jax.jit(step, donate_argnums=(1,))
+
+
+def jit_insert(bundle: ModelBundle):
+    """The jitted insert, ``(pstate, pack, slot, page_ids) -> pstate``, with
+    ``pstate`` donated: a prompt's pages are scattered into the pool in
+    place."""
+    return jax.jit(bundle.insert_paged, donate_argnums=(0,))
+
+
 class ServingEngine:
     """Continuous batching for ONE architecture (one compiled decode)."""
 
@@ -107,17 +174,7 @@ class ServingEngine:
         # recurrent state would integrate padded tokens -> exact lengths
         self.exact_len = self.cfg.family in ("ssm", "hybrid")
         self.pstate = bundle.init_paged(ec.n_slots, ec.n_pages, ec.page_size)
-        self.sched = {
-            "block_tables": jnp.zeros((ec.n_slots, ec.max_pages_per_seq),
-                                      jnp.int32),
-            "seq_lens": jnp.zeros((ec.n_slots,), jnp.int32),
-            "active": jnp.zeros((ec.n_slots,), bool),
-            "last_tok": jnp.zeros((ec.n_slots,), jnp.int32),
-            "out_buf": jnp.zeros((ec.n_slots, ec.max_out), jnp.int32),
-            "n_out": jnp.zeros((ec.n_slots,), jnp.int32),
-            "budget": jnp.zeros((ec.n_slots,), jnp.int32),
-            "key": jax.random.key(ec.seed),
-        }
+        self.sched = init_sched(ec)
         self.pending: collections.deque = collections.deque()
         self.finished: Dict[int, Request] = {}
         self._free_pages: List[int] = list(range(ec.n_pages - 1, 0, -1))
@@ -129,51 +186,9 @@ class ServingEngine:
         self.n_admitted = 0
         self.n_page_waits = 0
         self.slot_steps = 0
-        self._step = jax.jit(self._make_step())
+        self._step = jit_step(bundle, ec)
         self._prefill = jax.jit(bundle.prefill_paged)   # one trace per bucket
-        self._insert = jax.jit(bundle.insert_paged)     # one per page count
-
-    # ------------------------------------------------------------------
-    # the ONE jitted decode step
-
-    def _make_step(self):
-        ec, bundle = self.econf, self.bundle
-        n = ec.n_slots
-
-        def step(params, pstate, sd):
-            logits, pstate = bundle.decode_paged(
-                params, pstate, sd["block_tables"], sd["seq_lens"],
-                sd["last_tok"][:, None], sd["active"], ec.use_kernel)
-            if ec.temperature > 0:
-                key, sub = jax.random.split(sd["key"])
-                tok = jax.random.categorical(sub, logits / ec.temperature,
-                                             axis=-1)
-            else:
-                key, tok = sd["key"], jnp.argmax(logits, axis=-1)
-            tok = tok.astype(jnp.int32)
-            act = sd["active"]
-            row = jnp.arange(n)
-            idx = jnp.minimum(sd["n_out"], ec.max_out - 1)
-            out_buf = sd["out_buf"].at[row, idx].set(
-                jnp.where(act, tok, sd["out_buf"][row, idx]))
-            n_out = sd["n_out"] + act.astype(jnp.int32)
-            seq_lens = sd["seq_lens"] + act.astype(jnp.int32)
-            done = act & ((n_out >= sd["budget"]) | (tok == ec.eos_id))
-            return pstate, {
-                # release: a zeroed row points every future write at the
-                # scratch page; the host frees the physical pages
-                "block_tables": jnp.where(done[:, None], 0,
-                                          sd["block_tables"]),
-                "seq_lens": seq_lens,
-                "active": act & ~done,
-                "last_tok": jnp.where(act, tok, sd["last_tok"]),
-                "out_buf": out_buf,
-                "n_out": n_out,
-                "budget": sd["budget"],
-                "key": key,
-            }
-
-        return step
+        self._insert = jit_insert(bundle)               # one per page count
 
     # ------------------------------------------------------------------
     # admission

@@ -7,8 +7,21 @@ request in the batch and forces a full reallocation whenever the batch
 composition changes — exactly what continuous batching cannot afford.  Here
 the KV cache is a pool of fixed-size **pages** shared by all decode slots:
 
-  k_pages / v_pages : (L, n_pages, page_size, K, hd)   physical pool
+  k_pages / v_pages : (L, n_pages, page_size, K * hd)  physical pool
   block_tables      : (n_slots, max_pages) int32        logical -> physical
+
+One cache entry is one row of ``K * hd`` values, its kv heads side by side.
+That minor dimension is dense on the TPU's (16, 128) bf16 tiles wherever
+``K * hd`` is a multiple of 128 (a ``(.., K, hd)`` pool with hd = 64 pads
+every tile to twice its size and more), so the compiler keeps the pool in
+its row-major layout and three users share it: the decode step's in-place
+token write, the Pallas kernel's page DMA, and the insert's page scatter.
+The pool never moves.  The decode step carries it through the layer scan
+(the scan's carry, not its ``xs``/``ys``, which would slice and restack
+every layer), writes each layer's new token with one scatter at
+``[layer, page, pos % page_size]`` and hands the kernel the whole pool and
+the layer index; the serving engine donates it to the step and the insert,
+so each updates the one buffer in place.
 
 Page 0 is reserved as a **scratch page** (the allocator never hands it out):
 idle slots keep an all-zero block-table row, so the unconditional per-step
@@ -69,9 +82,9 @@ def init_paged(cfg: ModelConfig, n_slots: int, n_pages: int,
     K, hd, Lr = cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
     pstate = {}
     if cfg.family != "ssm":
-        pstate["k_pages"] = jnp.zeros((Lr, n_pages, page_size, K, hd),
+        pstate["k_pages"] = jnp.zeros((Lr, n_pages, page_size, K * hd),
                                       cfg.param_dtype)
-        pstate["v_pages"] = jnp.zeros((Lr, n_pages, page_size, K, hd),
+        pstate["v_pages"] = jnp.zeros((Lr, n_pages, page_size, K * hd),
                                       cfg.param_dtype)
     if cfg.family in ("ssm", "hybrid"):
         st = ssm_lib.init_ssm_state(cfg, n_slots)
@@ -142,10 +155,11 @@ def insert_paged(cfg: ModelConfig, pstate: dict, pack: dict, slot,
         for src, dst in (("k", "k_pages"), ("v", "v_pages")):
             t = pack[src][:, 0]                       # (L, S, K, hd)
             Lr, S = t.shape[0], t.shape[1]
+            t = t.reshape(Lr, S, -1)                  # (L, S, K * hd)
             pad = n_used * ps - S
             if pad:
-                t = jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            t = t.reshape(Lr, n_used, ps, *t.shape[2:])
+                t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+            t = t.reshape(Lr, n_used, ps, t.shape[-1])
             out[dst] = pstate[dst].at[:, page_ids].set(
                 t.astype(pstate[dst].dtype))
     for name in ("ssm_h", "ssm_conv", "cross_k", "cross_v"):
@@ -158,19 +172,22 @@ def insert_paged(cfg: ModelConfig, pstate: dict, pack: dict, slot,
 # ---------------------------------------------------------------------------
 # decode
 
-def _paged_decode_attention(ap, cfg: ModelConfig, h, pos_vec, kp, vp,
+def _paged_decode_attention(ap, cfg: ModelConfig, h, pos_vec, kp, vp, layer,
                             block_tables, lens_incl, window, use_kernel):
-    """One-token self-attention against the paged pool.  Writes the new K/V
-    at position ``pos_vec[b]`` of slot b's logical sequence (idle slots hit
-    scratch page 0 via their zeroed block-table row), then attends."""
+    """One-token self-attention of ``layer`` against the whole paged pool.
+    Writes the new K/V in place at position ``pos_vec[b]`` of slot b's
+    logical sequence (idle slots hit scratch page 0 via their zeroed
+    block-table row), then attends."""
     q, k_new, v_new = L._qkv(ap, cfg, h, h, pos_vec[:, None], pos_vec[:, None])
-    ps = kp.shape[1]
+    B, ps = pos_vec.shape[0], kp.shape[2]
     blk = pos_vec // ps
     page = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    kp = kp.at[page, pos_vec % ps].set(k_new[:, 0])
-    vp = vp.at[page, pos_vec % ps].set(v_new[:, 0])
-    out = ops.paged_attention(q, kp, vp, block_tables, lens_incl, window,
-                              use_kernel=use_kernel)
+    kp = kp.at[layer, page, pos_vec % ps].set(
+        k_new.reshape(B, -1).astype(kp.dtype))
+    vp = vp.at[layer, page, pos_vec % ps].set(
+        v_new.reshape(B, -1).astype(vp.dtype))
+    out = ops.paged_attention(q, kp, vp, layer, block_tables, lens_incl,
+                              window, use_kernel=use_kernel)
     return L.proj(ap, "wo", out, cfg), kp, vp
 
 
@@ -188,27 +205,31 @@ def decode_paged(params, cfg: ModelConfig, pstate: dict, block_tables,
     x = L.embed(params["tok"], cfg, tokens)
     pos_vec = seq_lens.astype(jnp.int32)
     lens_incl = jnp.where(active, seq_lens + 1, 0).astype(jnp.int32)
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    # the pools ride in the carry: each layer writes and reads them in place
+    carry0 = (x, pstate["k_pages"], pstate["v_pages"])
 
     if cfg.family == "encdec":
         window = jnp.int32(cfg.sliding_window or L.BIG_WINDOW)
 
         def body(carry, xs):
-            lp, kp, vp, xk, xv = xs
-            h = L.rms_norm(carry, lp["ln1"], cfg.norm_eps)
+            x, kp, vp = carry
+            lp, layer, xk, xv = xs
+            h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
             attn_out, kp, vp = _paged_decode_attention(
-                lp["attn"], cfg, h, pos_vec, kp, vp, block_tables,
+                lp["attn"], cfg, h, pos_vec, kp, vp, layer, block_tables,
                 lens_incl, window, use_kernel)
-            y = carry + attn_out
+            y = x + attn_out
             hx = L.rms_norm(y, lp["ln_x"], cfg.norm_eps)
             y = y + L.cross_attention(lp["xattn"], cfg, hx, xk, xv)
             h2 = L.rms_norm(y, lp["ln2"], cfg.norm_eps)
             y = y + L.mlp(lp["mlp"], cfg, h2)
-            return y, (kp, vp)
+            return (y, kp, vp), None
 
-        x, ys = jax.lax.scan(body, x, (params["dec_layers"],
-                                       pstate["k_pages"], pstate["v_pages"],
-                                       pstate["cross_k"], pstate["cross_v"]))
-        new_pstate = dict(pstate, k_pages=ys[0], v_pages=ys[1])
+        (x, kp, vp), _ = jax.lax.scan(
+            body, carry0, (params["dec_layers"], layer_ids,
+                           pstate["cross_k"], pstate["cross_v"]))
+        new_pstate = dict(pstate, k_pages=kp, v_pages=vp)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         return L.unembed(params["tok"], cfg, x)[:, 0], new_pstate
 
@@ -216,20 +237,21 @@ def decode_paged(params, cfg: ModelConfig, pstate: dict, block_tables,
     hybrid = cfg.family == "hybrid"
 
     def body(carry, xs):
+        x, kp, vp = carry
         if hybrid:
-            lp, kp, vp, w, sh, sconv = xs
+            lp, layer, w, sh, sconv = xs
         else:
-            lp, kp, vp, w = xs
-        h = L.rms_norm(carry, lp["ln1"], cfg.norm_eps)
+            lp, layer, w = xs
+        h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
         attn_out, kp, vp = _paged_decode_attention(
-            lp["attn"], cfg, h, pos_vec, kp, vp, block_tables,
+            lp["attn"], cfg, h, pos_vec, kp, vp, layer, block_tables,
             lens_incl, w, use_kernel)
         new_state = ()
         if hybrid:
             ssm_out, new_state = ssm_lib.ssm_decode_step(
                 lp["ssm"], cfg, {"h": sh, "conv": sconv}, h)
             attn_out = 0.5 * (attn_out + ssm_out)
-        y = carry + attn_out
+        y = x + attn_out
         h2 = L.rms_norm(y, lp["ln2"], cfg.norm_eps)
         if cfg.is_moe:
             moe_fn = (moe_lib.moe_mlp_sharded if cfg.moe_impl == "sharded"
@@ -239,15 +261,15 @@ def decode_paged(params, cfg: ModelConfig, pstate: dict, block_tables,
             m = L.mlp(lp["mlp"], cfg, h2)
         y = y + m
         if hybrid:
-            return y, (kp, vp, new_state["h"], new_state["conv"])
-        return y, (kp, vp)
+            return (y, kp, vp), (new_state["h"], new_state["conv"])
+        return (y, kp, vp), None
 
-    xs = (params["layers"], pstate["k_pages"], pstate["v_pages"], windows)
+    xs = (params["layers"], layer_ids, windows)
     if hybrid:
         xs = xs + (pstate["ssm_h"], pstate["ssm_conv"])
-    x, ys = jax.lax.scan(body, x, xs)
-    new_pstate = dict(pstate, k_pages=ys[0], v_pages=ys[1])
+    (x, kp, vp), ys = jax.lax.scan(body, carry0, xs)
+    new_pstate = dict(pstate, k_pages=kp, v_pages=vp)
     if hybrid:
-        new_pstate["ssm_h"], new_pstate["ssm_conv"] = ys[2], ys[3]
+        new_pstate["ssm_h"], new_pstate["ssm_conv"] = ys
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     return L.unembed(params["tok"], cfg, x)[:, 0], new_pstate

@@ -77,48 +77,58 @@ def test_flash_attention_block_shape_independence():
                                         (4, 4, 32, 4, 8),   # MHA
                                         (4, 1, 16, 16, 3)]) # MQA
 @pytest.mark.parametrize("window", [0, 16])
-def test_paged_attention_kernel(H, K, D, ps, M, window):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_kernel(H, K, D, ps, M, window, layer):
     """Pallas paged kernel (interpret) and the jnp gather path must both
-    match the oracle — mixed fill levels incl. an idle (len 0) slot."""
-    B, P = 4, 24
+    match the oracle — mixed fill levels incl. an idle (len 0) slot, read
+    from one layer of a three-layer pool of (page, entry, K * D) rows."""
+    B, P, Lr = 4, 24, 3
     ks = jax.random.split(jax.random.key(11), 4)
     q = jax.random.normal(ks[0], (B, 1, H, D))
-    kp = jax.random.normal(ks[1], (P, ps, K, D))
-    vp = jax.random.normal(ks[2], (P, ps, K, D))
+    kp = jax.random.normal(ks[1], (Lr, P, ps, K * D))
+    vp = jax.random.normal(ks[2], (Lr, P, ps, K * D))
     bt = jax.random.randint(ks[3], (B, M), 1, P)
     lens = jnp.array([1, ps + 1, M * ps, 0], jnp.int32)
     w = jnp.int32(window if window else 1 << 30)
+    lyr = jnp.int32(layer)
     want = np.asarray(ref.paged_attention_ref(
-        q, kp, vp, bt, lens, window=window or None)).reshape(B, 1, H * D)
-    got_kernel = ops.paged_attention(q, kp, vp, bt, lens, w,
+        q, kp, vp, layer, bt, lens, window=window or None)
+        ).reshape(B, 1, H * D)
+    got_kernel = ops.paged_attention(q, kp, vp, lyr, bt, lens, w,
                                      use_kernel=True, interpret=True)
-    got_jnp = ops.paged_attention(q, kp, vp, bt, lens, w, use_kernel=False)
+    got_jnp = ops.paged_attention(q, kp, vp, lyr, bt, lens, w,
+                                  use_kernel=False)
     np.testing.assert_allclose(np.asarray(got_kernel), want,
                                atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(np.asarray(got_jnp), want,
                                atol=2e-4, rtol=2e-4)
 
 
-def test_paged_attention_matches_contiguous():
+@pytest.mark.parametrize("H,K", [(4, 2), (4, 4)])          # GQA, MHA
+@pytest.mark.parametrize("layer", [0, 1])
+def test_paged_attention_matches_contiguous(H, K, layer):
     """A paged cache whose block table is a permutation must reproduce
-    plain end-aligned causal attention over the logically contiguous KV."""
-    B, H, K, D, ps, M = 2, 4, 2, 16, 8, 4
+    plain end-aligned causal attention over the logically contiguous KV,
+    from whichever layer of the pool holds it."""
+    B, D, ps, M, Lr = 2, 16, 8, 4, 2
     S = M * ps
     ks = jax.random.split(jax.random.key(12), 3)
     q = jax.random.normal(ks[0], (B, 1, H, D))
     k = jax.random.normal(ks[1], (B, S, K, D))
     v = jax.random.normal(ks[2], (B, S, K, D))
-    # scatter the contiguous KV into a shuffled physical pool
+    # scatter the contiguous KV into a shuffled physical pool; the other
+    # layer holds noise
     perm = np.array([[3, 6, 1, 5], [2, 7, 4, 8]], np.int32)
-    kp = jnp.zeros((9, ps, K, D))
-    vp = jnp.zeros((9, ps, K, D))
+    kp = jax.random.normal(ks[0], (Lr, 9, ps, K * D))
+    vp = jax.random.normal(ks[1], (Lr, 9, ps, K * D))
     for b in range(B):
         for j in range(M):
-            kp = kp.at[perm[b, j]].set(k[b, j * ps:(j + 1) * ps])
-            vp = vp.at[perm[b, j]].set(v[b, j * ps:(j + 1) * ps])
+            blk = slice(j * ps, (j + 1) * ps)
+            kp = kp.at[layer, perm[b, j]].set(k[b, blk].reshape(ps, K * D))
+            vp = vp.at[layer, perm[b, j]].set(v[b, blk].reshape(ps, K * D))
     lens = jnp.array([S, S], jnp.int32)
-    got = ops.paged_attention(q, kp, vp, jnp.asarray(perm), lens,
-                              jnp.int32(1 << 30), use_kernel=True,
+    got = ops.paged_attention(q, kp, vp, jnp.int32(layer), jnp.asarray(perm),
+                              lens, jnp.int32(1 << 30), use_kernel=True,
                               interpret=True)
     kr = jnp.repeat(k, H // K, 2).transpose(0, 2, 1, 3)
     vr = jnp.repeat(v, H // K, 2).transpose(0, 2, 1, 3)

@@ -48,8 +48,12 @@ def _batch(cfg, toks, key=7):
 # ---------------------------------------------------------------------------
 # paged cache contract: prefill -> insert -> K decode steps == full forward
 
+@pytest.mark.parametrize("use_kernel", [None, True])   # jnp twin, kernel
 @pytest.mark.parametrize("fam", list(FAMS))
-def test_paged_decode_matches_forward(fam):
+def test_paged_decode_matches_forward(fam, use_kernel):
+    """Prefill, insert and decode through the paged pool reproduce the
+    full forward, with the jnp twin and with the Pallas kernel (interpret
+    mode on the CPU) reading the pool."""
     cfg = _cfg(**FAMS[fam])
     b = build_model(cfg)
     params = b.init(jax.random.key(0))
@@ -82,7 +86,7 @@ def test_paged_decode_matches_forward(fam):
     for i in range(K):
         tok = jnp.zeros((2, 1), jnp.int32).at[slot, 0].set(toks[0, S + i])
         logits, pstate = b.decode_paged(params, pstate, bt, seq_lens, tok,
-                                        active)
+                                        active, use_kernel)
         np.testing.assert_allclose(
             np.asarray(logits[slot], np.float32),
             np.asarray(full_logits[0, P + S + i], np.float32),
@@ -118,6 +122,38 @@ def test_engine_matches_generate_greedy():
     # eviction returned every page and slot to the free lists
     assert len(engine._free_pages) == econf.n_pages - 1
     assert sorted(engine._free_slots) == [0, 1]
+
+
+def test_engine_donates_page_pool():
+    """The insert and the decode step take the pool donated: the buffers a
+    call starts from are deleted once it has run (the pool is written in
+    place), and greedy outputs still match ``generate()``."""
+    cfg = _cfg(**FAMS["dense"])
+    b = build_model(cfg)
+    params = b.init(jax.random.key(0))
+    econf = EngineConfig(n_slots=2, page_size=4, n_pages=32,
+                         max_pages_per_seq=8, max_out=16, buckets=(8, 16))
+    engine = ServingEngine(b, params, econf)
+    rng = np.random.RandomState(5)
+    reqs = [(rng.randint(0, cfg.vocab_size, (int(n),)).astype(np.int32),
+             int(m)) for n, m in [(5, 6), (11, 4), (7, 9)]]
+    rids = [engine.submit(t, max_new=m) for t, m in reqs]
+
+    def pools():
+        return engine.pstate["k_pages"], engine.pstate["v_pages"]
+
+    before = pools()
+    engine._try_admit()                           # inserts only
+    assert all(x.is_deleted() for x in before)
+    while engine.busy:
+        before = pools()
+        engine.tick()                             # a decode step each tick
+        assert all(x.is_deleted() for x in before)
+        assert not any(x.is_deleted() for x in pools())
+    for rid, (toks, m) in zip(rids, reqs):
+        want = generate(b, params, jnp.asarray(toks)[None], max_new=m)
+        assert engine.finished[rid].out.tolist() == \
+            np.asarray(want[0]).tolist(), f"req {rid}"
 
 
 def test_engine_eos_and_budget_clamp():
