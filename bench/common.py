@@ -2,6 +2,7 @@
 compile counting, host spans, the profiler window and the result line."""
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import math
@@ -62,14 +63,21 @@ def cell(name: str) -> tuple:
     return w, conf, traffic, limits
 
 
+def load_file(path: str):
+    """The module in the Python file ``path``, loaded once a process."""
+    name = "bench_file_" + hashlib.sha1(path.encode()).hexdigest()[:16]
+    mod = sys.modules.get(name)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return mod
+
+
 def metric_reader(name: str):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(os.path.join(BENCH_DIR, "metrics", name + ".py")).read
 
 
 def check_device(chips: int):
@@ -83,13 +91,15 @@ def check_device(chips: int):
     return devs[:chips]
 
 
+def memory_peaks(devs) -> list:
+    """Each device's peak bytes in use so far (0 where not reported)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devs]
+
+
 def device_info(devs) -> dict:
-    peak = 0
-    for d in devs:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs), "memory_peak_bytes": peak}
+            "count": len(devs), "memory_peak_bytes": max(memory_peaks(devs))}
 
 
 class CompileCounter:

@@ -1,18 +1,24 @@
-"""Federation cells: ``FederatedRunner.run_round`` on one chip.
+"""Federation cells: ``FederatedRunner.run_round`` on the cell's chips.
 
-Set-up builds the runner from the configuration and the cell's job file,
-replaces its models with weights the benchmark draws from the seed (one
-jitted call, in the served type), and drives the first rounds through the
+Set-up builds the runner from the configuration and the cell's job file
+(on more than one chip with the program's federated mesh, which puts the
+client stack on its "data" axis), replaces its models with weights the
+benchmark draws from the seed (one jitted call, in the served type, placed
+as the program placed its own), and drives the first rounds through the
 window's own call and feed: they compile the round and give the readings
-the reference is compared on.  The window then calls
-``run_round(evaluate=False)`` and ``sync()`` until ``--seconds`` have
-passed; ``round_s`` is the window over the rounds it completed.
+the reference is compared on.  The client stack the compiled round
+returns must lie on every chip of the cell, with no more than its share of
+the clients' bytes on each (``stack_chips``, ``clients_per_chip``).  The
+window then calls ``run_round(evaluate=False)`` and ``sync()`` until
+``--seconds`` have passed; ``round_s`` is the window over the rounds it
+completed.
 
-After the window the runner is freed and the plain reference (``reference``
-below: the ML-ECS round of client CCL/AMT steps, MMA aggregation, SE-CCL and
-redistribution, in float32 at HIGHEST precision) replays the same first
-rounds from the same weights and feed.  Compared, leaf by leaf, for every
-client and both server models:
+After the window the runner is freed and the plain reference (``Reference``
+below, over each model's reference module: the ML-ECS round of client
+CCL/AMT steps, MMA aggregation, SE-CCL and redistribution, in float32 at
+HIGHEST precision) replays the same first rounds from the same weights and
+feed on one chip.  Compared, leaf by leaf, for every client and both server
+models:
 
 - ``grad_gap``: Adam's first moment after round 1 (the gradients as the
   optimizer got them);
@@ -27,6 +33,7 @@ the median leaf's are left out of both.
 from __future__ import annotations
 
 import gc
+import math
 import sys
 import time
 
@@ -79,7 +86,7 @@ def _draw_pieces(key, slm: dict, llm: dict, n: int):
     client's and the server SLM's trainable leaves, the server LLM."""
     ks = _keys(key)
     dt = jnp.dtype(slm["dtype"])
-    bb = model._draw(ks["slm"], model.backbone_shapes(slm), dt,
+    bb = model._draw(ks["slm"], model.backbone(slm).backbone_shapes(slm), dt,
                      slm["vocab_size"])
     per = [model._draw(ks["client"](j), model.personal_shapes(slm, 0.0), dt)
            for j in range(n)]
@@ -93,15 +100,19 @@ def draw_pieces(wseed: int, slm: dict, llm: dict, n: int):
         jax.random.key(wseed))
 
 
-def draw_program_weights(wseed: int, slm: dict, llm: dict, n: int):
+def draw_program_weights(wseed: int, slm: dict, llm: dict, n: int,
+                         placement=None):
     """The same weights in the program's layout, in one jitted call:
-    (client stack with a leading client axis, server SLM, server LLM)."""
+    (client stack with a leading client axis, server SLM, server LLM),
+    made where ``placement`` (the same tuple of shardings) puts them."""
     def f(key):
         bb, per, srv, big = _draw_pieces(key, slm, llm, n)
         stack = {k: jnp.stack([v] * n) for k, v in bb.items()}
         stack.update({k: jnp.stack([p[k] for p in per]) for k in per[0]})
         return stack, {**bb, **srv}, big
-    return jax.jit(f)(jax.random.key(wseed))
+    if placement is None:
+        return jax.jit(f)(jax.random.key(wseed))
+    return jax.jit(f, out_shardings=placement)(jax.random.key(wseed))
 
 
 # ---------------------------------------------------------------------------
@@ -178,9 +189,9 @@ class Reference:
             soft, mods, fused = model.connector(
                 p["connector"], m, batch["modality_feats"],
                 batch["modality_mask"], prec_)
-            lm = model.lm_ce(model.forward(p, m, batch["tokens"], soft,
-                                           prec_),
-                             batch["tokens"], batch["loss_mask"])
+            logits = model.backbone(m).forward(p, m, batch["tokens"], soft,
+                                               prec_)
+            lm = model.lm_ce(logits, batch["tokens"], batch["loss_mask"])
             if ccl_weight:
                 anc = fused if anchor is None else anchor
                 lm = lm + ccl_weight * model.contrastive(
@@ -203,10 +214,12 @@ class Reference:
             def total(lt, st):
                 l_llm = mlecs(lt, lbb, llm, batch, None, proto["ccl_weight"])
                 l_slm = mlecs(st, sbb, slm, batch, None, 0.0)
-                y_l = model.forward(model.nest({**lbb, **lt}), llm,
-                                    batch["tokens"], None, prec_)
-                y_s = model.forward(model.nest({**sbb, **st}), slm,
-                                    batch["tokens"], None, prec_)
+                y_l = model.backbone(llm).forward(
+                    model.nest({**lbb, **lt}), llm, batch["tokens"], None,
+                    prec_)
+                y_s = model.backbone(slm).forward(
+                    model.nest({**sbb, **st}), slm, batch["tokens"], None,
+                    prec_)
                 kt = proto["kt_weight"]
                 return (l_llm + kt * pooled_kl(y_l, jax.lax.stop_gradient(y_s))
                         + l_slm + kt * pooled_kl(y_s,
@@ -353,11 +366,14 @@ def _host(tree):
 # ---------------------------------------------------------------------------
 # the run
 
-def build_runner(conf: dict, job: dict, seed: int):
+def build_runner(conf: dict, job: dict, seed: int, devs):
+    """The program's runner of the cell; on more than one device with the
+    program's federated mesh, which must span exactly ``devs``."""
     from bench.serving import program_config
     from repro.core.channel import ChannelSpec
     from repro.core.federated import FederatedRunner
     from repro.core.spec import ClientCohort, FederationSpec
+    from repro.launch.mesh import make_federated_mesh
 
     slm = program_config(conf["clients"]["model"])
     llm = program_config(conf["server_llm"])
@@ -373,17 +389,46 @@ def build_runner(conf: dict, job: dict, seed: int):
         kt_weight=proto["kt_weight"], seed=seed, robust=job["robust"],
         channel=ChannelSpec(**chan) if chan else None)
     data = corpus(model.np_seed(seed, 4), job, conf["clients"]["model"])
-    return FederatedRunner(spec, data)
+    if len(devs) == 1:
+        return FederatedRunner(spec, data)
+    mesh = make_federated_mesh()
+    if set(mesh.devices.flat) != set(devs):
+        raise RuntimeError(f"the federated mesh spans {mesh.devices.size} "
+                           f"devices, the cell {len(devs)}")
+    return FederatedRunner(spec, data, mesh=mesh)
+
+
+def _models(runner) -> tuple:
+    (rt,) = runner.cohorts
+    return (("clients", rt.stacked_params), ("server_slm", runner.server_slm),
+            ("server_llm", runner.server_llm))
 
 
 def layout(runner) -> dict:
     """Paths, shapes and dtypes of the runner's three models."""
-    (rt,) = runner.cohorts
     return {name: {k: (tuple(v.shape), str(v.dtype))
                    for k, v in model.flatten(tree).items()}
-            for name, tree in (("clients", rt.stacked_params),
-                               ("server_slm", runner.server_slm),
-                               ("server_llm", runner.server_llm))}
+            for name, tree in _models(runner)}
+
+
+def placement(runner) -> tuple:
+    """The shardings of the runner's three models, flat."""
+    return tuple({k: v.sharding for k, v in model.flatten(tree).items()}
+                 for _, tree in _models(runner))
+
+
+def stack_spread(runner) -> tuple:
+    """(devices that hold a part of the client stack, the most clients'
+    worth of its bytes one device holds): parameters and optimizer state,
+    each leaf's shard counted on every device that holds one."""
+    (rt,) = runner.cohorts
+    held, total = {}, 0
+    for x in jax.tree.leaves((rt.stacked_params, rt.stacked_opt)):
+        shard = math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for d in x.sharding.device_set:
+            held[d] = held.get(d, 0) + shard
+        total += x.nbytes
+    return len(held), max(held.values()) / (total / rt.n)
 
 
 def install(runner, want_all: dict, stack, srv, big) -> None:
@@ -444,12 +489,14 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
     n = conf["clients"]["n"]
     wseed = model.np_seed(seed, 1)
     n_check = job["check_rounds"]
-    runner = build_runner(conf, job, model.np_seed(seed, 5))
+    runner = build_runner(conf, job, model.np_seed(seed, 5), devs)
+    built_peaks = common.memory_peaks(devs)
     (rt,) = runner.cohorts
     want = layout(runner)
+    place = placement(runner) if runner.mesh is not None else None
     rt.stacked_params = runner.server_slm = runner.server_llm = None
     gc.collect()
-    stack, srv, big = draw_program_weights(wseed, slm, llm, n)
+    stack, srv, big = draw_program_weights(wseed, slm, llm, n, place)
     init = {f"client{j}": {k: np.array(v[j]) for k, v in stack.items()
                            if model.is_trainable(k)} for j in range(n)}
     init["server_slm"] = {k: np.array(v) for k, v in srv.items()
@@ -471,6 +518,7 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
         runner.sync()
         if r == 0 or r == n_check - 1:
             program_readings(rt, runner, init, stage)
+    stack_chips, per_chip = stack_spread(runner)
     n_compile_setup = counter.n
     prof = common.Profiler(bool(trace), w["name"])
     setup_s = time.perf_counter() - t_start
@@ -498,6 +546,7 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
     counter.close()
     comm = runner.comm_stats
     dev = common.device_info(devs)
+    end_peaks = common.memory_peaks(devs)
     runner.close()
     del runner, rt
     gc.collect()
@@ -505,7 +554,10 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
     print(f"set-up {setup_s:.3f} s; window: {rounds} rounds in {window:.3f} "
           f"s, per round min {per.min():.4f} median {np.median(per):.4f} "
           f"max {per.max():.4f} s; compiles in window {n_compile_window}; "
-          f"uplink bytes {comm['uplink_bytes']}", file=sys.stderr, flush=True)
+          f"uplink bytes {comm['uplink_bytes']}; peak GB a chip after the "
+          f"runner's build {[round(b / 1e9, 3) for b in built_peaks]}, at "
+          f"the window's end {[round(b / 1e9, 3) for b in end_peaks]}",
+          file=sys.stderr, flush=True)
 
     # the reference, with the program's state freed
     t_ref = time.perf_counter()
@@ -534,12 +586,18 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
                       (f"{tag}_change_gap", c["change_gap"],
                        limits["change_gap"])]
     distinct = rows_distinct(feed)
+    # a chip holds its share of the clients and less than half a client
+    # more (leaves the round returns replicated, as the delivered LoRA)
+    share = -(-n // w["chips"]) + 0.5
     checks = [("grad_gap", shown["grad_gap"], limits["grad_gap"]),
               ("change_gap", shown["change_gap"], limits["change_gap"]),
               ("rows_distinct", int(distinct), 1),
-              ("compiles_in_window", n_compile_window, 0)] + extra
+              ("compiles_in_window", n_compile_window, 0),
+              ("stack_chips", stack_chips, w["chips"]),
+              ("clients_per_chip", per_chip, share)] + extra
     correct = (shown["grad_gap"] <= limits["grad_gap"]
-               and shown["change_gap"] <= limits["change_gap"] and distinct)
+               and shown["change_gap"] <= limits["change_gap"] and distinct
+               and stack_chips == w["chips"] and per_chip <= share)
 
     if trace:
         from bench import flops
@@ -548,7 +606,7 @@ def run(w, conf, job, limits, seed, seconds, trace, devs, t_start,
         summary = trace_lib.summarize(path, prof) if path else None
         ctx = {"cell": w["name"], "conf": conf, "traffic": job,
                "trace": summary, "device_kind": dev["kind"],
-               "rounds_traced": traced,
+               "chips": dev["count"], "rounds_traced": traced,
                "round_flops": flops.round_flops(conf, job),
                "codec_bytes": flops.codec_bytes(conf, job)}
         metrics = common.collect_per_layer(w, ctx, summary)

@@ -7,21 +7,14 @@ from __future__ import annotations
 
 import math
 
-
-def _layer_matmul(m: dict) -> int:
-    """Weights a token meets in one layer's matmuls (attention + MLP)."""
-    d, H, K, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                      m["head_dim"], m["d_ff"])
-    mult = 3 if m["activation"] in ("silu", "geglu") else 2
-    return d * (H + 2 * K) * hd + H * hd * d + mult * d * f
+from bench import model
 
 
-def _layer_lora(m: dict) -> int:
-    d, H, K, hd, r = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                      m["head_dim"], m["lora_rank"])
-    dims = {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
-            "wo": (H * hd, d)}
-    return sum(r * (dims[t][0] + dims[t][1]) for t in m["lora_targets"])
+def _lora(m: dict) -> int:
+    """LoRA weights one token meets over all layers (both factors)."""
+    r = m["lora_rank"]
+    return sum(n * r * (i + o)
+               for n, i, o in model.backbone(m).lora_dims(m).values())
 
 
 def causal_pairs(S: int) -> int:
@@ -33,9 +26,9 @@ def forward_flops(m: dict, n_tok: int, pairs: int, logit_pos: int,
                   lora: bool = True) -> int:
     """One forward pass over ``n_tok`` tokens with ``pairs`` attention
     score pairs and logits at ``logit_pos`` positions."""
-    L, H, hd = m["n_layers"], m["n_heads"], m["head_dim"]
-    w = _layer_matmul(m) + (_layer_lora(m) if lora else 0)
-    return (2 * n_tok * L * w + 4 * pairs * H * hd * L
+    ref = model.backbone(m)
+    w = ref.matmul_weights(m) + (_lora(m) if lora else 0)
+    return (2 * n_tok * w + pairs * ref.pair_flops(m)
             + 2 * logit_pos * m["d_model"] * m["vocab_size"])
 
 
@@ -43,12 +36,10 @@ def train_flops(m: dict, B: int, S: int) -> int:
     """Forward, backward to the activations, and the LoRA gradients, of a
     batch of B causal sequences of length S (logits at every position)."""
     n, pairs = B * S, B * causal_pairs(S)
-    L, H, hd = m["n_layers"], m["n_heads"], m["head_dim"]
     fwd = forward_flops(m, n, pairs, n)
-    attn = 4 * pairs * H * hd * L
+    attn = pairs * model.backbone(m).pair_flops(m)
     bwd = fwd + attn                   # attention backward is 4 matmuls
-    lora_w = 2 * n * L * _layer_lora(m)
-    return fwd + bwd + lora_w
+    return fwd + bwd + 2 * n * _lora(m)
 
 
 def round_flops(conf: dict, job: dict) -> int:
@@ -79,14 +70,14 @@ def decode_flops(m: dict, ctx: int) -> int:
 
 
 def live_kv_bytes(m: dict, lens, page_size: int, itemsize: int = 2) -> int:
-    """Bytes one paged decode step must move: the live K/V pages of each
-    active slot (``lens``: cached entries incl. the new token), plus its
-    query and output rows, over every layer."""
-    K, H, hd, L = m["n_kv_heads"], m["n_heads"], m["head_dim"], m["n_layers"]
-    pages = sum(math.ceil(x / page_size) for x in lens)
-    kv = pages * page_size * K * hd * 2 * itemsize
-    qo = len(lens) * H * hd * 2 * itemsize
-    return (kv + qo) * L
+    """Bytes one paged decode step must move for active slots holding
+    ``lens`` cached entries (the new token included)."""
+    return model.backbone(m).live_kv_bytes(m, lens, page_size, itemsize)
+
+
+def kv_layers(m: dict) -> int:
+    """Layers that keep a paged K/V cache: paged-attention calls a step."""
+    return model.backbone(m).kv_layers(m)
 
 
 def codec_bytes(conf: dict, job: dict) -> int:
@@ -99,15 +90,10 @@ def codec_bytes(conf: dict, job: dict) -> int:
     if not chan:
         return 0
     m = conf["clients"]["model"]
-    block = chan.get("block", 128)
-    L, r = m["n_layers"], m["lora_rank"]
-    d, H, K, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    dims = {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
-            "wo": (H * hd, d)}
+    block, r = chan.get("block", 128), m["lora_rank"]
     rows = 0
-    for t in m["lora_targets"]:
-        i, o = dims[t]
-        rows += math.ceil(L * i * r / block) + math.ceil(L * r * o / block)
+    for n, i, o in model.backbone(m).lora_dims(m).values():
+        rows += math.ceil(n * i * r / block) + math.ceil(n * r * o / block)
     q = rows * (block * 4 + block + 4)      # f32 in, int8 + scale out
     dq = rows * (block + 4 + block * 4)     # int8 + scale in, f32 out
     n = conf["clients"]["n"]

@@ -1,6 +1,6 @@
 """Model FLOPs of the tokens the decode steps produced in the traced slice
-(each at its own context length), over the slice, over the chip's bf16
-peak."""
+(each at its own context length), over the slice, over the bf16 peak of
+all the cell's chips."""
 from bench import flops, peaks, serving
 
 
@@ -12,5 +12,5 @@ def read(ctx):
     f = sum(flops.decode_flops(m, c) for _, live in ticks for _, c in live)
     if not f:
         return None
-    peak = peaks.peak(ctx["device_kind"])["bf16_flops"]
+    peak = peaks.peak(ctx["device_kind"])["bf16_flops"] * ctx["chips"]
     return 100.0 * f / ctx["trace"]["window_s"] / peak

@@ -18,6 +18,6 @@ def read(ctx):
     ps = ctx["conf"]["engine"]["page_size"]
     per_step = sum(flops.live_kv_bytes(m, [c for _, c in live], ps)
                    for _, live in ticks) / len(ticks)
-    steps = calls / m["n_layers"]
+    steps = calls / flops.kv_layers(m)
     bw = peaks.peak(ctx["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * per_step * steps / sec / bw

@@ -1,17 +1,20 @@
-"""The plain reference of the decoder the benchmark runs, and its weights.
+"""What the benchmark's references share, whatever the backbone: the
+weights, the ML-ECS parts and the precision of the plain reference.
 
 Everything here is written from the published description and imports
 nothing of the program under test:
 
+- ``backbone``: the reference module of a model's backbone, named by the
+  model's ``reference`` key (``bench/references/dense.py`` without it;
+  that file says what a reference module defines);
 - ``draw_model``: flat weights in the program's layout ('/'-joined paths),
-  drawn from a key on the device, in the configuration's ``dtype``.
-- ``forward``: a decoder-only transformer (pre-norm RMSNorm, rotary
-  positions, grouped-query causal attention, GeLU or SiLU-gated MLP, LoRA on
-  the attention projections, tied embeddings) in float32 with matmuls at
-  ``Precision.HIGHEST``.  ``prec="fp8"`` rounds every matmul operand to
-  float8 e4m3 first: the lower-precision control.
-- the multimodal connector (per-modality projectors, fusion MLP, soft-prompt
-  generator) and the Gram-volume contrastive loss of the ML-ECS paper.
+  drawn from a key on the device, in the configuration's ``dtype``;
+- the matmuls of the reference in float32 at ``Precision.HIGHEST``, and
+  with ``prec="fp8"`` on operands rounded to float8 e4m3 first: the
+  lower-precision control;
+- the LoRA adapters and the multimodal connector (per-modality projectors,
+  fusion MLP, soft-prompt generator) that every client trains, the
+  Gram-volume contrastive loss and the next-token loss of the ML-ECS paper.
 
 A model is described by a dict of sizes (the configuration files under
 ``bench/configs``), with the program's own key names.
@@ -19,14 +22,28 @@ A model is described by a dict of sizes (the configuration files under
 from __future__ import annotations
 
 import math
+import os
 import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import common
+
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
+DEFAULT_REFERENCE = "bench/references/dense.py"
+
+
+def backbone(m: dict):
+    """The reference module named by ``m["reference"]`` (a path from the
+    checkout's root to a file under ``bench/``), loaded once a process."""
+    rel = m.get("reference", DEFAULT_REFERENCE)
+    path = os.path.realpath(os.path.join(common.ROOT, rel))
+    if not path.startswith(os.path.realpath(common.BENCH_DIR) + os.sep):
+        raise ValueError(f"reference {rel!r} is not a file under bench/")
+    return common.load_file(path)
 
 
 def padded_vocab(m: dict) -> int:
@@ -51,39 +68,15 @@ def _normal(key, path, shape, std, dtype):
             ).astype(dtype)
 
 
-def backbone_shapes(m: dict) -> dict:
-    """{path: (shape, std)} of the frozen backbone (std None = zeros)."""
-    d, H, K, hd, f, L = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                         m["head_dim"], m["d_ff"], m["n_layers"])
-    s = {"tok/embed": ((padded_vocab(m), d), 1.0 / math.sqrt(d)),
-         "final_norm": ((d,), None),
-         "layers/ln1": ((L, d), None), "layers/ln2": ((L, d), None),
-         "layers/attn/wq": ((L, d, H * hd), 1.0 / math.sqrt(d)),
-         "layers/attn/wk": ((L, d, K * hd), 1.0 / math.sqrt(d)),
-         "layers/attn/wv": ((L, d, K * hd), 1.0 / math.sqrt(d)),
-         "layers/attn/wo": ((L, H * hd, d), 1.0 / math.sqrt(H * hd)),
-         "layers/mlp/w_up": ((L, d, f), 1.0 / math.sqrt(d)),
-         "layers/mlp/w_down": ((L, f, d), 1.0 / math.sqrt(f))}
-    if m.get("qk_norm"):
-        s["layers/attn/q_norm"] = ((L, hd), None)
-        s["layers/attn/k_norm"] = ((L, hd), None)
-    if m["activation"] in ("silu", "geglu"):
-        s["layers/mlp/w_gate"] = ((L, d, f), 1.0 / math.sqrt(d))
-    return s
-
-
 def personal_shapes(m: dict, lora_b_std: float) -> dict:
-    """{path: (shape, std)} of the trainable leaves: LoRA on the attention
-    projections and the multimodal connector."""
-    d, H, K, hd, L, r = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                         m["head_dim"], m["n_layers"], m["lora_rank"])
-    dims = {"wq": (d, H * hd), "wk": (d, K * hd), "wv": (d, K * hd),
-            "wo": (H * hd, d)}
+    """{path: (shape, std)} of the trainable leaves: LoRA on the
+    projections the backbone's ``lora_dims`` names and the multimodal
+    connector."""
+    d, r = m["d_model"], m["lora_rank"]
     s = {}
-    for t in m["lora_targets"]:
-        i, o = dims[t]
-        s[f"layers/attn/{t}_lora_a"] = ((L, i, r), 1.0 / math.sqrt(i))
-        s[f"layers/attn/{t}_lora_b"] = ((L, r, o), lora_b_std or None)
+    for base, (n, i, o) in backbone(m).lora_dims(m).items():
+        s[f"{base}_lora_a"] = ((n, i, r), 1.0 / math.sqrt(i))
+        s[f"{base}_lora_b"] = ((n, r, o), lora_b_std or None)
     if m.get("n_modalities", 0) > 0:
         M, fd, c, n = (m["n_modalities"], m["modality_dim"], latent(m),
                        m["n_soft_tokens"])
@@ -141,7 +134,7 @@ def draw_model(key, m: dict, lora_b_std: float = 0.0):
     """Flat weights of one model, backbone and trainable leaves, from
     ``key``, in the model's ``dtype``."""
     dt = jnp.dtype(m["dtype"])
-    flat = _draw(key, backbone_shapes(m), dt, m["vocab_size"])
+    flat = _draw(key, backbone(m).backbone_shapes(m), dt, m["vocab_size"])
     flat.update(_draw(key, personal_shapes(m, lora_b_std), dt))
     return flat
 
@@ -155,7 +148,7 @@ def is_lora(path: str) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# the reference forward pass
+# the reference's precision and the pieces of its layers
 
 def _q8(x):
     return x.astype(jnp.float8_e4m3fn).astype(F32)
@@ -194,73 +187,6 @@ def rope(x, pos, theta):
     cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _lin(ap, name, x, m, prec):
-    y = mm(x, ap[name], prec)
-    a = ap.get(f"{name}_lora_a")
-    if a is not None:
-        y = y + (m["lora_alpha"] / m["lora_rank"]) * mm(
-            mm(x, a, prec), ap[f"{name}_lora_b"], prec)
-    return y
-
-
-def _layer(m, prec, x, lp, pos):
-    B, S, _ = x.shape
-    H, K, D = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    eps = m.get("norm_eps", 1e-6)
-    ap = lp["attn"]
-    h = rms(x, lp["ln1"], eps)
-    q = _lin(ap, "wq", h, m, prec).reshape(B, S, H, D)
-    k = _lin(ap, "wk", h, m, prec).reshape(B, S, K, D)
-    v = _lin(ap, "wv", h, m, prec).reshape(B, S, K, D)
-    if m.get("qk_norm"):
-        q, k = rms(q, ap["q_norm"], eps), rms(k, ap["k_norm"], eps)
-    q, k = rope(q, pos, m["rope_theta"]), rope(k, pos, m["rope_theta"])
-    q = q.reshape(B, S, K, H // K, D)
-    s = ein("bqkgd,bskd->bkgqs", q, k, prec) / math.sqrt(D)
-    causal = pos[None, :] <= pos[:, None]
-    s = jnp.where(causal, s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
-    o = ein("bkgqs,bskd->bqkgd", w, v, prec).reshape(B, S, H * D)
-    x = x + _lin(ap, "wo", o, m, prec)
-    h2 = rms(x, lp["ln2"], eps)
-    mp = lp["mlp"]
-    up = mm(h2, mp["w_up"], prec)
-    if m["activation"] == "silu":
-        g = mm(h2, mp["w_gate"], prec)
-        hid = g * jax.nn.sigmoid(g) * up
-    else:
-        hid = gelu(up)
-    return x + mm(hid, mp["w_down"], prec)
-
-
-def hidden(p, m: dict, tokens, prefix=None, prec: str = "f32"):
-    """Final-norm hidden states (B, P+S, d) of nested params ``p`` over
-    ``tokens`` (B, S) behind an optional embedding prefix (B, P, d)."""
-    x = jnp.take(p["tok"]["embed"], tokens, axis=0).astype(F32)
-    if prefix is not None:
-        x = jnp.concatenate([prefix.astype(F32), x], axis=1)
-    pos = jnp.arange(x.shape[1])
-
-    @jax.checkpoint
-    def body(x, lp):
-        return _layer(m, prec, x, lp, pos), None
-
-    x, _ = jax.lax.scan(body, x, p["layers"])
-    return rms(x, p["final_norm"], m.get("norm_eps", 1e-6))
-
-
-def forward(p, m: dict, tokens, prefix=None, prec: str = "f32"):
-    """Logits (B, P+S, V)."""
-    return mm(hidden(p, m, tokens, prefix, prec), p["tok"]["embed"].T, prec)
-
-
-def forward_at(p, m: dict, tokens, prefix, where, prec: str = "f32"):
-    """Logits (B, n, V) at positions ``where`` (B, n) only."""
-    h = hidden(p, m, tokens, prefix, prec)
-    h = jnp.take_along_axis(h, where[..., None], axis=1)
-    return mm(h, p["tok"]["embed"].T, prec)
 
 
 # ---------------------------------------------------------------------------

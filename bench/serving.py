@@ -19,10 +19,10 @@ before every tick, whether the head of the queue had a free slot; where it
 stays queued through that tick, its admission waited on free pages.
 
 After the window the engine is drained, its state freed, and the plain
-reference (``bench.model.forward``) scores a sample of the finished
-requests, the longest and one from every slot among them: the widest gap
-by which a served token's logit lies below the reference's best at its
-position.
+reference (the model's reference module, ``bench.model.backbone``) scores a
+sample of the finished requests, the longest and one from every slot among
+them: the widest gap by which a served token's logit lies below the
+reference's best at its position.
 """
 from __future__ import annotations
 
@@ -261,13 +261,14 @@ def reference_gaps(flat, m, reqs, prefix, outs, pick, prec_ctl=None):
     precision puts first (the control)."""
     p = model.nest({k: v for k, v in flat.items()
                     if not k.startswith("connector/")})
+    ref = model.backbone(m)
     max_p = max(int(reqs["prompt_len"][i]) for i in pick)
     max_o = max(len(outs[i]) for i in pick)
     seq = max_p + max_o
     seq = int(2 ** math.ceil(math.log2(max(seq, 16))))
 
     def logits_fn(prec):
-        return jax.jit(lambda p, toks, pre, where: model.forward_at(
+        return jax.jit(lambda p, toks, pre, where: ref.forward_at(
             p, m, toks, pre, where, prec))
 
     f_ref = logits_fn("f32")
@@ -380,7 +381,7 @@ def run(w, conf, traffic, limits, seed, seconds, trace, devs, t_start,
 
     if trace:
         metrics = trace_metrics(w, conf, traffic, prof, led, reqs, window,
-                                n_sub, dev["kind"], wm)
+                                n_sub, dev, wm)
         dev.update(metrics.pop("_device"))
         breakdown = metrics.pop("_breakdown")
     else:
@@ -396,7 +397,7 @@ def run(w, conf, traffic, limits, seed, seconds, trace, devs, t_start,
     return res, checks
 
 
-def trace_metrics(w, conf, traffic, prof, led, reqs, window, n_sub, kind,
+def trace_metrics(w, conf, traffic, prof, led, reqs, window, n_sub, dev,
                   wm):
     """Per-layer metrics of the traced slice of the window, and those of
     the whole window's record (``wm``, from :func:`window_metrics`)."""
@@ -405,7 +406,8 @@ def trace_metrics(w, conf, traffic, prof, led, reqs, window, n_sub, kind,
     summary = trace_lib.summarize(path, prof) if path else None
     ctx = {"cell": w["name"], "conf": conf, "traffic": traffic,
            "trace": summary, "ledger": led, "reqs": reqs,
-           "window": window, "n_sub": n_sub, "device_kind": kind,
+           "window": window, "n_sub": n_sub, "device_kind": dev["kind"],
+           "chips": dev["count"],
            "window_metrics": wm,
            "trace_span": ((prof.t0 - led.t0, prof.t1 - led.t0)
                           if prof.t0 else None)}

@@ -29,7 +29,7 @@ from bench import common, trace
 SCOPES = ("device_phase", "ccl", "amt", "channel", "mma", "server_phase",
           "redistribute")
 PROGRAM_SPANS = ("fed.", "serve.")
-DEVICE = "/device:TPU:0"
+DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 _WRAP = re.compile(r"^([\w.]+)\((.*)\)$")
 
 
@@ -56,24 +56,27 @@ def _arg(v):
 
 
 def load(path: str) -> tuple:
-    """(ops, spans) of a ``.trace.json.gz``: device 0's XLA operations as
-    (op name, start ns, end ns), and the host spans (the program's and the
-    harness's) as (name, start ns, end ns, {argument: value})."""
+    """(ops, spans) of a ``.trace.json.gz``: each device's XLA operations
+    as {device number: [(op name, start ns, end ns)]}, and the host spans
+    (the program's and the harness's) as (name, start ns, end ns,
+    {argument: value})."""
     with gzip.open(path) as f:
         events = json.load(f)["traceEvents"]
     procs = {e["pid"]: e["args"]["name"] for e in events
              if e.get("ph") == "M" and e.get("name") == "process_name"}
     lines = {(e["pid"], e["tid"]): e["args"]["name"] for e in events
              if e.get("ph") == "M" and e.get("name") == "thread_name"}
-    ops, spans = [], []
+    ops, spans = {}, []
     for e in events:
         if e.get("ph") != "X":
             continue
         a = int(round(e["ts"] * 1e3))
         b = a + int(round(e.get("dur", 0) * 1e3))
         proc = procs.get(e["pid"], "")
-        if proc == DEVICE and lines.get((e["pid"], e["tid"])) == "XLA Ops":
-            ops.append((e.get("args", {}).get("tf_op", ""), a, b))
+        dev = DEVICE.match(proc)
+        if dev and lines.get((e["pid"], e["tid"])) == "XLA Ops":
+            ops.setdefault(int(dev.group(1)), []).append(
+                (e.get("args", {}).get("tf_op", ""), a, b))
         elif proc.startswith("/host:CPU") and (
                 e["name"] in trace.HOST_SPANS
                 or e["name"].startswith(PROGRAM_SPANS)):
@@ -100,14 +103,16 @@ def _innermost(spans) -> list:
 
 def reduce(ops, spans) -> dict:
     """Busy and idle seconds of device 0, the idle seconds under each
-    innermost host span, the device seconds under each path of scopes, and
-    the program's own spans (name, start ns, end ns, arguments)."""
-    leaves = trace._leaves(ops)
+    innermost host span, the device seconds per device under each path of
+    scopes, and the program's own spans (name, start ns, end ns,
+    arguments)."""
     scope_s: dict = {}
-    for name, a, b in leaves:
-        k = scope_path(name)
-        scope_s[k] = scope_s.get(k, 0.0) + (b - a) * 1e-9
-    busy = trace._union(ops)
+    for dev_ops in ops.values():
+        for name, a, b in trace._leaves(dev_ops):
+            k = scope_path(name)
+            scope_s[k] = scope_s.get(k, 0.0) + (b - a) * 1e-9
+    scope_s = {k: v / max(1, len(ops)) for k, v in scope_s.items()}
+    busy = trace._union(ops.get(0, []))
     gaps = [(x[1], y[0]) for x, y in zip(busy, busy[1:])]
     if busy and spans:
         first = min(a for _, a, _, _ in spans)

@@ -66,9 +66,10 @@ def _leaves(events):
 
 def reduce(planes: list, window_s: float, top: int = 10) -> dict:
     """``planes``: [(plane name, {line name: [(event, start_ns, end_ns)]})].
-    Returns busy and window seconds, device seconds and call counts
-    (summed over devices) per program and per operation label (of leaf
-    operations: a loop's time is its body's), and the breakdown."""
+    Returns busy and window seconds (busy averaged over the devices), the
+    number of devices, device seconds and call counts (summed over devices)
+    per program and per operation label (of leaf operations: a loop's time
+    is its body's), and the breakdown."""
     devices = [(n, lines) for n, lines in planes
                if re.match(r"^/device:TPU:\d+$", n)]
     hosts = [lines for n, lines in planes if n.startswith("/host:CPU")]
@@ -105,7 +106,7 @@ def reduce(planes: list, window_s: float, top: int = 10) -> dict:
     gaps = sorted(gaps, key=lambda g: g[0] - g[1])[:top]
     top_ops = sorted(ops.items(), key=lambda kv: -kv[1][0])[:top]
     return {"busy_s": busy_total / n_dev, "window_s": window_s,
-            "programs": programs, "labels": ops,
+            "devices": n_dev, "programs": programs, "labels": ops,
             "breakdown": {
                 "device_ops": [[k, v[0] / n_dev] for k, v in top_ops],
                 "idle_gaps": [[doing(a, b), (b - a) * 1e-9]
@@ -125,11 +126,11 @@ def summarize(path: str, prof) -> dict:
 
 
 def time_of(summary: dict, pattern: str, table: str = "programs"):
-    """(seconds, calls) of the programs (``table="programs"``) or operation
-    labels (``"labels"``) whose name matches ``pattern``."""
+    """(seconds, calls) per device of the programs (``table="programs"``)
+    or operation labels (``"labels"``) whose name matches ``pattern``."""
     rx = re.compile(pattern)
     s = c = 0
     for k, (sec, n) in summary[table].items():
         if rx.search(k):
             s, c = s + sec, c + n
-    return s, c
+    return s / summary["devices"], c / summary["devices"]

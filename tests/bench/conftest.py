@@ -39,15 +39,21 @@ def tiny_serve():
     return w, conf, traffic, dict(limits, checked_tokens=10)
 
 
-@pytest.fixture
-def tiny_fed():
-    """(workload, configuration, job, limits) of ``fed720m.fused`` at toy
-    widths: a toy SLM pair and a toy server LLM of another width."""
+def tiny_fed_cell(name: str = "fed720m.fused") -> tuple:
+    """(workload, configuration, job, limits) of the federation cell
+    ``name`` at toy widths: a toy SLM pair and a toy server LLM of another
+    width."""
     from bench import common
-    w, conf, job, limits = common.cell("fed720m.fused")
+    w, conf, job, limits = common.cell(name)
     conf = copy.deepcopy(conf)
     conf["clients"]["model"] = _tiny(conf["clients"]["model"])
     conf["clients"]["model"]["connector_dim"] = 48
     conf["server_llm"] = _tiny(conf["server_llm"], d=96, kv=2, vocab=700)
     job = dict(job, seq_len=24, template_len=4, samples=400)
     return w, conf, job, limits
+
+
+@pytest.fixture
+def tiny_fed():
+    """:func:`tiny_fed_cell` of ``fed720m.fused``."""
+    return tiny_fed_cell()

@@ -122,6 +122,48 @@ def test_trace_reduction_busy_programs_and_gaps():
     assert "while" not in ops          # a loop's time is its body's
 
 
+def _four_chips(ops):
+    """The same XLA Ops line on four devices."""
+    return [(f"/device:TPU:{i}", {"XLA Ops": list(ops)}) for i in range(4)]
+
+
+def test_device_time_readers_read_per_chip_on_four_devices():
+    ops = [_ev("%fusion.1 = f32[8] fusion(...)", 0, 300), _ev(KERNEL, 300,
+                                                              400)]
+    one = trace_lib.reduce([("/device:TPU:0", {"XLA Ops": ops})], 1e-3)
+    four = trace_lib.reduce(_four_chips(ops), 1e-3)
+    assert (one["devices"], four["devices"]) == (1, 4)
+    assert trace_lib.time_of(one, "paged", "labels") == \
+        trace_lib.time_of(four, "paged", "labels") == (pytest.approx(1e-4), 1)
+    assert four["busy_s"] == one["busy_s"] == pytest.approx(4e-4)
+    # round.mfu over the peak of all the cell's chips
+    read = common.metric_reader("round.mfu")
+    ctx = {"trace": {"window_s": 2.0}, "rounds_traced": 3,
+           "round_flops": 70e12, "device_kind": "TPU v5 lite"}
+    mfu1 = read(dict(ctx, chips=1))
+    assert mfu1 == pytest.approx(100 * 3 * 70e12 / 2.0 / 197e12)
+    assert read(dict(ctx, chips=4)) == pytest.approx(mfu1 / 4)
+
+
+def test_collective_reader_per_traced_round_per_chip():
+    read = common.metric_reader("round.collective_ms")
+    ops = [_ev("%fusion.1 = f32[8] fusion(...)", 0, 300),
+           _ev("%all-reduce.3 = f32[8] all-reduce(...)", 300, 340),
+           _ev("%all-gather-start.1 = (f32[8]) all-gather-start(...)", 340,
+               345),
+           _ev("%all-gather-done.1 = f32[32] all-gather-done(...)", 345,
+               360),
+           _ev("%collective-permute.2 = f32[8] collective-permute(...)", 360,
+               380),
+           _ev("%reduce.4 = f32[] reduce(...)", 380, 400)]
+    ctx = {"trace": trace_lib.reduce(_four_chips(ops), 1e-3),
+           "rounds_traced": 2}
+    assert read(ctx) == pytest.approx(80e-3 / 2)       # 80 us a chip
+    ctx["trace"] = trace_lib.reduce(_four_chips(ops[:1] + ops[-1:]), 1e-3)
+    assert read(ctx) is None                           # none on the path
+    assert read({"trace": None, "rounds_traced": 2}) is None
+
+
 def test_flops_against_hand_counts_at_published_widths():
     _, conf, job, _ = common.cell("fed720m.fused")
     slm = conf["clients"]["model"]

@@ -39,24 +39,29 @@ IDLE = {"run_round": 10, "fed.round": 10 + 27 + 13 + 10 + 20, "fed.begin": 10,
         "fed.scatter": 5}
 
 
-def _write(root, cell, ops, host):
+def _write(root, cell, ops, host, devices=1):
     """A profiler trace of ``cell`` where the harness's profiler keeps it:
-    the ``.xplane.pb`` it looks for and the ``.trace.json.gz`` beside it."""
+    the ``.xplane.pb`` it looks for and the ``.trace.json.gz`` beside it.
+    ``ops`` is one device's list, or a list of lists, one a device."""
     d = os.path.join(root, ".bench_trace", cell, "plugins", "profile", "t")
     os.makedirs(d)
     open(os.path.join(d, "h.xplane.pb"), "wb").close()
-    ev = [{"ph": "M", "pid": 3, "name": "process_name",
-           "args": {"name": "/device:TPU:0"}},
-          {"ph": "M", "pid": 3, "tid": 2, "name": "thread_name",
-           "args": {"name": "XLA Modules"}},
-          {"ph": "M", "pid": 3, "tid": 3, "name": "thread_name",
-           "args": {"name": "XLA Ops"}},
-          {"ph": "M", "pid": 701, "name": "process_name",
-           "args": {"name": "/host:CPU"}},
-          {"ph": "X", "pid": 3, "tid": 2, "ts": 0, "dur": 2000,
-           "name": "jit_round_fn(1)", "args": {}}]
-    ev += [{"ph": "X", "pid": 3, "tid": 3, "ts": a, "dur": b - a,
-            "name": "fusion.1", "args": {"tf_op": tf}} for tf, a, b in ops]
+    per_dev = ops if ops and isinstance(ops[0], list) else [ops] * devices
+    ev = [{"ph": "M", "pid": 701, "name": "process_name",
+           "args": {"name": "/host:CPU"}}]
+    for i, dev_ops in enumerate(per_dev):
+        pid = 3 + i
+        ev += [{"ph": "M", "pid": pid, "name": "process_name",
+                "args": {"name": f"/device:TPU:{i}"}},
+               {"ph": "M", "pid": pid, "tid": 2, "name": "thread_name",
+                "args": {"name": "XLA Modules"}},
+               {"ph": "M", "pid": pid, "tid": 3, "name": "thread_name",
+                "args": {"name": "XLA Ops"}},
+               {"ph": "X", "pid": pid, "tid": 2, "ts": 0, "dur": 2000,
+                "name": "jit_round_fn(1)", "args": {}}]
+        ev += [{"ph": "X", "pid": pid, "tid": 3, "ts": a, "dur": b - a,
+                "name": "fusion.1", "args": {"tf_op": tf}}
+               for tf, a, b in dev_ops]
     ev += [{"ph": "X", "pid": 701, "tid": 9, "ts": a, "dur": b - a,
             "name": n, "args": {k: str(v) for k, v in args.items()}}
            for n, a, b, args in host]
@@ -111,6 +116,22 @@ def test_fed_readers_per_traced_round(trace_root):
     got = {n: _read(n, ctx) for n in want}
     assert got == pytest.approx({n: v / 2 for n, v in want.items()})
     assert ctx["spans"]["busy_s"] > 0          # reduced once, for every reader
+
+
+def test_fed_readers_read_per_chip_on_four_devices(trace_root):
+    """Four devices: the scopes' device time is each device's, averaged;
+    the idle under the host spans is device 0's, as on one device."""
+    # devices 1-3 ran the round with half of the server phase's time
+    half = [(n, a, b if "server_phase" not in n else a + (b - a) // 2)
+            for n, a, b in OPS]
+    _write(trace_root, "fed720m.fused.4chip", [OPS, half, half, half], FED)
+    ctx = {"cell": "fed720m.fused.4chip", "trace": {}, "rounds_traced": 2}
+    assert _read("device_phase.ms", ctx) == pytest.approx(250e-3 / 2)
+    assert _read("server_phase.ms", ctx) == pytest.approx(
+        (160 + 3 * 80) / 4 * 1e-3 / 2)
+    assert _read("assemble.idle_ms", ctx) == pytest.approx(85e-3 / 2)
+    assert ctx["spans"]["idle_by_span"] == pytest.approx(
+        {k: v * 1e-6 for k, v in IDLE.items()})
 
 
 def test_fed_readers_find_nothing_in_a_trace_without_program_spans(
